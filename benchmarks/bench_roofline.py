@@ -76,7 +76,8 @@ def _kernel_cases(rng, quick: bool) -> list[dict]:
              run=lambda blk: gp_ops.gram_project(
                  x, v, block_n=blk["block_n"], block_k=blk["block_k"],
                  double_buffer=blk.get("double_buffer", False))),
-        dict(kernel="featurize_gram", tune_dims=dict(n=n),
+        dict(kernel="featurize_gram",
+             tune_dims=dict(n=n, m=m, d=d, itemsize=4),
              cost_dims=dict(n=n, m=m, d=d), itemsize=4,
              run=lambda blk: fg_ops.featurize_gram(
                  xm, w, block_n=blk["block_n"],

@@ -53,7 +53,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -435,13 +434,15 @@ class ProtocolEngine:
                        top_k=top_k, eig_floor=self.cfg.eig_floor,
                        impl=self.impl, assume_full=assume_full)
         spec_in = P(axis)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(spec_in, spec_in),
-                       out_specs=(P(), P(), P(), P(), P()),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(spec_in, spec_in),
+                           out_specs=(P(), P(), P(), P(), P()),
+                           check_vma=False)
         with mesh:
-            raw = jax.device_put(jnp.asarray(raw),
-                                 NamedSharding(mesh, P(axis)))
+            # straight from the host into per-device user shards: staging
+            # the whole raw stack on the default device first would need
+            # all N users' rows in ONE device's memory
+            raw = jax.device_put(raw, NamedSharding(mesh, P(axis)))
             nv = jax.device_put(nv, NamedSharding(mesh, P(axis)))
             return jax.jit(fn)(raw, nv)
 
@@ -566,10 +567,10 @@ class ProtocolEngine:
         body = partial(_sharded_protocol, axis=axis, top_k=top_k,
                        eig_floor=self.cfg.eig_floor, impl=self.impl)
         spec_in = P(axis)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(spec_in, spec_in),
-                       out_specs=(P(), P(), P(), P()),  # replicated
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(spec_in, spec_in),
+                           out_specs=(P(), P(), P(), P()),  # replicated
+                           check_vma=False)
         with mesh:
             feats = jax.device_put(feats, NamedSharding(mesh, P(axis)))
             nv = jax.device_put(nv, NamedSharding(mesh, P(axis)))

@@ -74,7 +74,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -653,8 +652,9 @@ class MembershipEngine:
             labels, margin = _verdict_from_affinity(aff, *floors)
             return labels, aff, margin
 
-        fn = shard_map(body, mesh=mesh, in_specs=(P(), P(axis), P(axis)),
-                       out_specs=(P(), P(), P()), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), P(axis), P(axis)),
+                           out_specs=(P(), P(), P()), check_vma=False)
         with mesh:
             v_w = jax.device_put(jnp.asarray(v, jnp.float32),
                                  NamedSharding(mesh, P()))

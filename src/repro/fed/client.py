@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import optim
+from repro.fed.fedavg import AVG_PRECISION
 
 PyTree = Any
 LossFn = Callable[[PyTree, dict], jax.Array]
@@ -95,8 +96,9 @@ def _run_lps(params: PyTree, batches: dict, weights: jax.Array,
     w = weights.astype(jnp.float32)
     w = w / jnp.sum(w)
     avg = jax.tree.map(
-        lambda x: jnp.tensordot(w, x.astype(jnp.float32),
-                                axes=1).astype(x.dtype), new_params)
+        lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=1,
+                                precision=AVG_PRECISION).astype(x.dtype),
+        new_params)
     return avg, losses
 
 
@@ -218,7 +220,8 @@ def masked_lps_round(params: PyTree, x: jax.Array, y: jax.Array,
         # average (NaN * 0 == NaN).
         m = mask.reshape((-1,) + (1,) * (l.ndim - 1))
         lf = jnp.where(m > 0, l.astype(jnp.float32), 0.0)
-        return jnp.where(nonempty, jnp.tensordot(wn, lf, axes=1),
+        avg = jnp.tensordot(wn, lf, axes=1, precision=AVG_PRECISION)
+        return jnp.where(nonempty, avg,
                          p0.astype(jnp.float32)).astype(p0.dtype)
 
     avg = jax.tree.map(fedavg_leaf, new_params, params)

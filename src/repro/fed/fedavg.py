@@ -8,7 +8,12 @@ import jax.numpy as jnp
 
 PyTree = Any
 
-__all__ = ["weighted_mean", "fedavg"]
+__all__ = ["weighted_mean", "fedavg", "AVG_PRECISION"]
+
+#: Precision of every parameter-average contraction (FedAvg, the GPS
+#: average).  At default precision a TPU rounds the operands of an f32 dot
+#: to bf16, which would round every averaged parameter to 8 mantissa bits.
+AVG_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def weighted_mean(trees: Sequence[PyTree], weights: Sequence[float]) -> PyTree:

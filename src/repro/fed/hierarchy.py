@@ -22,7 +22,8 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.fed.fedavg import fedavg as _fedavg, weighted_mean as _wmean
+from repro.fed.fedavg import (AVG_PRECISION, fedavg as _fedavg,
+                               weighted_mean as _wmean)
 from repro.fed import partition as part
 
 PyTree = Any
@@ -78,7 +79,8 @@ def gps_aggregate_stacked(stack: PyTree, cluster_weights: jax.Array,
     def leaf(path, v):
         if not is_common(path):
             return v
-        num = jnp.tensordot(wn, v.astype(jnp.float32), axes=1)
+        num = jnp.tensordot(wn, v.astype(jnp.float32), axes=1,
+                            precision=AVG_PRECISION)
         if axis is not None:
             num = jax.lax.psum(num, axis)
         avg = jnp.broadcast_to(num[None], v.shape)
@@ -107,7 +109,7 @@ def masked_cluster_mean(values: PyTree, onehot: jax.Array,
 
     def reduce_leaf(v):
         vf = v.astype(jnp.float32)
-        num = jnp.einsum("u...,ut->t...", vf, w)
+        num = jnp.einsum("u...,ut->t...", vf, w, precision=AVG_PRECISION)
         if axis is not None:
             num = jax.lax.psum(num, axis)
         out = num / denom.reshape((-1,) + (1,) * (num.ndim - 1))

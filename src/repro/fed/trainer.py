@@ -49,7 +49,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -268,10 +267,10 @@ def _sharded_round_fn(mesh: Mesh, axis: str, statics_vals: tuple):
     the static values hash by value / identity)."""
     statics = dict(zip(_STATICS, statics_vals))
     spec_c = P(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_round_body, **statics, axis=axis), mesh=mesh,
         in_specs=(spec_c, P()) + (spec_c,) * 7 + (P(),),
-        out_specs=(spec_c, spec_c), check_rep=False))
+        out_specs=(spec_c, spec_c), check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)
@@ -279,11 +278,11 @@ def _sharded_run_fn(mesh: Mesh, axis: str, statics_vals: tuple,
                     global_rounds: int):
     statics = dict(zip(_STATICS, statics_vals))
     spec_c = P(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_run_scanned, **statics, axis=axis,
                 global_rounds=global_rounds),
         mesh=mesh, in_specs=(spec_c,) * 8 + (P(),),
-        out_specs=(P(None, axis), P(None, axis)), check_rep=False))
+        out_specs=(P(None, axis), P(None, axis)), check_vma=False))
 
 
 def _pad_clusters(stacks: PyTree, n_pad: int) -> PyTree:

@@ -99,8 +99,9 @@ def featurize_gram_pallas(x: jax.Array, w: jax.Array, block_n: int = 128,
     ``n`` must be a ``block_n`` multiple and ``m``/``d`` lane multiples
     (128); ``ops.py`` pads.  ``W`` and the ``(d, d)`` accumulator stay
     VMEM-resident across the whole row walk (``m*d + d^2 + bn*(m+d)``
-    floats — twice the ``bn*m`` term with ``double_buffer``; fine for the
-    protocol's d <= 1k feature widths).
+    floats — twice the ``bn*m`` term with ``double_buffer``);
+    ``kernels.tuning.featurize_gram_vmem_bytes`` counts the DMA path and
+    the lowered tile plan sizes ``block_n`` to fit the scoped VMEM.
     """
     n, m = x.shape
     mw, d = w.shape
@@ -116,7 +117,7 @@ def featurize_gram_pallas(x: jax.Array, w: jax.Array, block_n: int = 128,
         return pl.pallas_call(
             functools.partial(_kernel_db, n_steps=n_steps, block_n=block_n),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),     # X streamed by DMA
+                pl.BlockSpec(memory_space=pl.ANY),     # X streamed by DMA
                 pl.BlockSpec(memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -27,7 +27,8 @@ def featurize_gram(x: jax.Array, w: jax.Array,
     contribute nothing to the Gram); the ``1/n`` normalization lives with
     the caller, matching ``kernels.gram``.  Unpinned ``block_n`` /
     ``double_buffer`` resolve through ``kernels.tuning`` (DMA streaming
-    defaults on for lowered backends only).
+    defaults on for lowered backends only, with the row tile sized to the
+    chip's scoped VMEM from ``m``, ``d`` and the compute dtype).
     """
     if compute_dtype not in COMPUTE_DTYPES:
         raise ValueError(f"compute_dtype must be one of {COMPUTE_DTYPES}, "
@@ -36,7 +37,9 @@ def featurize_gram(x: jax.Array, w: jax.Array,
     d = w.shape[1]
     interpret = dispatch.resolve_interpret(interpret)
     if block_n is None or double_buffer is None:
-        blocks = tuning.get_blocks("featurize_gram", n=n)
+        blocks = tuning.get_blocks(
+            "featurize_gram", n=n, m=m, d=d,
+            itemsize=2 if compute_dtype == "bf16" else 4)
         block_n = block_n or blocks["block_n"]
         if double_buffer is None:
             double_buffer = blocks["double_buffer"]

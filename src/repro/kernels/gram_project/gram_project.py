@@ -121,7 +121,7 @@ def gram_project_pallas(x: jax.Array, v: jax.Array, block_n: int = 128,
             functools.partial(_kernel_db, n_steps=n_steps, block_n=block_n),
             grid=(k // block_k,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),     # X streamed by DMA
+                pl.BlockSpec(memory_space=pl.ANY),     # X streamed by DMA
                 pl.BlockSpec((d, block_k), lambda kk: (0, kk)),
             ],
             out_specs=pl.BlockSpec((1, block_k), lambda kk: (0, kk)),

@@ -54,8 +54,8 @@ def _wkv_impl(r, k, v, logw, u, state, *, chunk: int, compute_dtype: str,
         return jnp.pad(t, ((0, 0), (0, sp - s), (0, hdp - hd)))
 
     u2 = jnp.pad(jnp.broadcast_to(u.astype(jnp.float32)[None], (b, h, hd)
-                                  ).reshape(b * h, hd),
-                 ((0, 0), (0, hdp - hd)))
+                                  ).reshape(b * h, 1, hd),
+                 ((0, 0), (0, 0), (0, hdp - hd)))
     s02 = jnp.pad(state.astype(jnp.float32).reshape(b * h, hd, hd),
                   ((0, 0), (0, hdp - hd), (0, hdp - hd)))
     out, st = wkv_chunked_pallas(pack(r), pack(k), pack(v), pack(logw),
@@ -102,9 +102,9 @@ def _linear_scan_impl(log_a, x, h0, *, chunk: int, block_d: int,
                        ((0, 0), (0, sp - s), (0, dp - d)))
 
     h, hT = linear_scan_pallas(pad(log_a), pad(x),
-                               jnp.pad(h0.astype(jnp.float32),
-                                       ((0, 0), (0, dp - d))),
+                               jnp.pad(h0.astype(jnp.float32)[:, None],
+                                       ((0, 0), (0, 0), (0, dp - d))),
                                chunk=c, block_d=bd,
                                compute_dtype=compute_dtype,
                                interpret=interpret)
-    return h[:, :s, :d], hT[:, :d]
+    return h[:, :s, :d], hT[:, 0, :d]

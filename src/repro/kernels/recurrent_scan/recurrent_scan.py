@@ -6,7 +6,11 @@ chunks of ``C`` tokens, the chunk axis is the FASTEST grid dimension (so
 it iterates sequentially for a fixed batch row), and the recurrent state
 rides across chunk steps in an fp32 VMEM scratch accumulator — loaded
 from the initial-state operand at the first chunk, flushed to the
-final-state output at the last.  Within a chunk the recurrence is
+final-state output at the last.  Per-row operands (the RWKV bonus ``u``,
+the RG-LRU carry ``h0``/``h_last``) travel as ``(rows, 1, lanes)`` with
+block ``(1, 1, lanes)``: Mosaic needs the last two block dims to be
+(8, 128)-divisible or equal to the array's, and a ``(1, lanes)`` block on
+a ``(rows, lanes)`` array is neither.  Within a chunk the recurrence is
 closed-form: pairwise decay ratios ``exp(cum[t] - cum[s]) <= 1`` are
 computed as log differences (nothing overflows because log-decays are
 ``<= 0``), which turns the sequential scan into matmuls.
@@ -38,6 +42,27 @@ def _cdtype(compute_dtype: str):
     return jnp.bfloat16 if compute_dtype == "bf16" else jnp.float32
 
 
+def _lower_tri(shape: tuple[int, ...], strict: bool) -> jax.Array:
+    """Bool mask of ``t >= s`` over axes ``(0, 1)`` of ``shape`` (``t > s``
+    when ``strict``), built from iota compares at full rank: Mosaic can
+    neither lower ``jnp.tril`` of a bool array (it truncates through i8)
+    nor reshape a bool ``(C, C)`` mask to ``(C, C, 1)``."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return row > col if strict else row >= col
+
+
+def _cumsum_rows(x):
+    """Inclusive prefix sum over axis 0 as a lower-triangular matmul: Mosaic
+    has no ``cumsum`` lowering, and the MXU does the ``(C, C) @ (C, lanes)``
+    product at full fp32 precision."""
+    return jax.lax.dot_general(
+        _lower_tri((x.shape[0],) * 2, strict=False).astype(jnp.float32), x,
+        (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # RWKV-6 WKV: matrix state per (batch, head) row
 # ---------------------------------------------------------------------------
@@ -54,7 +79,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
     lw = lw_ref[0]                                    # (C, hdp) f32, <= 0
     rc, kc, vc = r_ref[0], k_ref[0], v_ref[0]         # (C, hdp) f32
-    cum = jnp.cumsum(lw, axis=0)
+    cum = _cumsum_rows(lw)
     cum_prev = cum - lw                               # cum[t-1]
 
     # state passthrough: o_state[t] = (r_t * exp(cum[t-1])) . S
@@ -65,7 +90,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
     # intra-chunk: A[t,s,d] = exp(cum[t-1,d] - cum[s,d]) for s < t (<= 1)
     diff = cum_prev[:, None, :] - cum[None, :, :]     # (C, C, hdp)
-    tri = jnp.tril(jnp.ones(diff.shape[:2], bool), k=-1)[:, :, None]
+    tri = _lower_tri(diff.shape, strict=True)
     a = jnp.where(tri, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
     w_ts = (rc.astype(cd)[:, None] * a.astype(cd) * kc.astype(cd)[None]
             ).astype(jnp.float32).sum(axis=-1)        # (C, C) f32 acc
@@ -74,15 +99,14 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
         preferred_element_type=jnp.float32)
 
     # bonus on the current token: (r_t . (u * k_t)) v_t
-    o_bonus = ((rc * u_ref[...]) * kc).sum(axis=-1, keepdims=True) * vc
+    o_bonus = ((rc * u_ref[0]) * kc).sum(axis=-1, keepdims=True) * vc
 
     o_ref[0] = (o_state + o_intra + o_bonus).astype(o_ref.dtype)
 
     # next chunk state: S' = exp(cum[C-1]) S + sum_s exp(cum[C-1]-cum[s]) k v^T
-    dec_total = jnp.exp(cum[-1])                      # (hdp,)
-    k_dec = (kc * jnp.exp(jnp.minimum(cum[-1][None, :] - cum, 0.0))
-             ).astype(cd)
-    st_ref[...] = (dec_total[:, None] * st_ref[...]
+    cum_last = cum[cum.shape[0] - 1:, :]              # (1, hdp), static
+    k_dec = (kc * jnp.exp(jnp.minimum(cum_last - cum, 0.0))).astype(cd)
+    st_ref[...] = (jnp.exp(cum_last).T * st_ref[...]
                    + jax.lax.dot_general(
                        k_dec, vc.astype(cd), (((0,), (0,)), ((), ())),
                        preferred_element_type=jnp.float32))
@@ -97,7 +121,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 def wkv_chunked_pallas(r, k, v, logw, u, state, chunk: int = 64,
                        compute_dtype: str = "bf16", interpret: bool = False
                        ) -> tuple[jax.Array, jax.Array]:
-    """``r/k/v/logw (BH, S, hdp)`` f32, ``u (BH, hdp)`` f32,
+    """``r/k/v/logw (BH, S, hdp)`` f32, ``u (BH, 1, hdp)`` f32,
     ``state (BH, hdp, hdp)`` f32 -> ``(out (BH, S, hdp) f32, final state)``.
 
     ``S`` must be a ``chunk`` multiple and ``hdp`` a lane multiple of 128
@@ -113,7 +137,7 @@ def wkv_chunked_pallas(r, k, v, logw, u, state, chunk: int = 64,
     n_chunks = s // chunk
     grid = (bh, n_chunks)
     seq_spec = pl.BlockSpec((1, chunk, hdp), lambda i, c: (i, c, 0))
-    row_spec = pl.BlockSpec((1, hdp), lambda i, c: (i, 0))
+    row_spec = pl.BlockSpec((1, 1, hdp), lambda i, c: (i, 0, 0))
     mat_spec = pl.BlockSpec((1, hdp, hdp), lambda i, c: (i, 0, 0))
     out, st = pl.pallas_call(
         functools.partial(_wkv_kernel, n_chunks=n_chunks,
@@ -140,14 +164,14 @@ def _linear_scan_kernel(la_ref, x_ref, h0_ref, o_ref, hT_ref, st_ref, *,
 
     @pl.when(c == 0)
     def _load_state():
-        st_ref[...] = h0_ref[...]
+        st_ref[...] = h0_ref[0]
 
     la = la_ref[0]                                    # (C, bd) f32, <= 0
     xc = x_ref[0]                                     # (C, bd) f32
-    cum = jnp.cumsum(la, axis=0)
+    cum = _cumsum_rows(la)
     # W[t,s,d] = exp(cum[t,d] - cum[s,d]) for s <= t (diagonal incl.: ratio 1)
     diff = cum[:, None, :] - cum[None, :, :]          # (C, C, bd)
-    tri = jnp.tril(jnp.ones(diff.shape[:2], bool))[:, :, None]
+    tri = _lower_tri(diff.shape, strict=False)
     w = jnp.where(tri, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
     h_intra = (w.astype(cd) * xc.astype(cd)[None, :, :]
                ).astype(jnp.float32).sum(axis=1)      # (C, bd) f32 acc
@@ -157,7 +181,7 @@ def _linear_scan_kernel(la_ref, x_ref, h0_ref, o_ref, hT_ref, st_ref, *,
 
     @pl.when(c == n_chunks - 1)
     def _flush_state():
-        hT_ref[...] = st_ref[...]
+        hT_ref[0] = st_ref[...]
 
 
 @functools.partial(jax.jit,
@@ -166,8 +190,8 @@ def _linear_scan_kernel(la_ref, x_ref, h0_ref, o_ref, hT_ref, st_ref, *,
 def linear_scan_pallas(log_a, x, h0, chunk: int = 64, block_d: int = 256,
                        compute_dtype: str = "fp32", interpret: bool = False
                        ) -> tuple[jax.Array, jax.Array]:
-    """``log_a/x (B, S, Dp)`` f32, ``h0 (B, Dp)`` f32 ->
-    ``(h (B, S, Dp) f32, h_last (B, Dp) f32)``.
+    """``log_a/x (B, S, Dp)`` f32, ``h0 (B, 1, Dp)`` f32 ->
+    ``(h (B, S, Dp) f32, h_last (B, 1, Dp) f32)``.
 
     ``S`` must be a ``chunk`` multiple and ``Dp`` a ``block_d`` multiple
     (lane-rounded; ``ops.py`` pads — zero ``log_a``/``x`` padding is an
@@ -183,7 +207,7 @@ def linear_scan_pallas(log_a, x, h0, chunk: int = 64, block_d: int = 256,
     n_chunks = s // chunk
     grid = (b, dp // block_d, n_chunks)
     seq_spec = pl.BlockSpec((1, chunk, block_d), lambda i, j, c: (i, c, j))
-    row_spec = pl.BlockSpec((1, block_d), lambda i, j, c: (i, j))
+    row_spec = pl.BlockSpec((1, 1, block_d), lambda i, j, c: (i, 0, j))
     h, hT = pl.pallas_call(
         functools.partial(_linear_scan_kernel, n_chunks=n_chunks,
                           compute_dtype=compute_dtype),
@@ -191,7 +215,7 @@ def linear_scan_pallas(log_a, x, h0, chunk: int = 64, block_d: int = 256,
         in_specs=[seq_spec, seq_spec, row_spec],
         out_specs=(seq_spec, row_spec),
         out_shape=(jax.ShapeDtypeStruct((b, s, dp), jnp.float32),
-                   jax.ShapeDtypeStruct((b, dp), jnp.float32)),
+                   jax.ShapeDtypeStruct((b, 1, dp), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
     )(log_a, x, h0)

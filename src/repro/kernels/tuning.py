@@ -35,10 +35,21 @@ from repro.kernels import dispatch
 
 __all__ = ["KERNELS", "shape_bucket", "cache_key", "cache_path",
            "heuristic_blocks", "get_blocks", "autotune", "lookup",
-           "record", "clear_cache", "divisor_block"]
+           "record", "clear_cache", "divisor_block",
+           "featurize_gram_vmem_bytes", "vmem_budget_bytes",
+           "SCOPED_VMEM_BYTES"]
 
 _ENV = "REPRO_TUNE_CACHE"
 _LANE = 128
+_SUBLANE = 16          # row-tile quantum valid for both f32 and bf16
+
+#: Default scoped-VMEM limit of a Mosaic kernel by device kind (lowercase
+#: substrings of ``jax.devices()[0].device_kind``, matched as
+#: ``launch/roofline.HW_TABLE`` matches).  Only kinds whose limit a
+#: compile has confirmed are listed: v5e refuses a kernel that allocates
+#: more than 16 MiB.  A lowered tile plan on any other kind raises.
+SCOPED_VMEM_BYTES: dict[str, int] = {"v5 lite": 16 << 20, "v5e": 16 << 20}
+_VMEM_RESERVE = 1 << 20        # left to Mosaic's internal scratch
 
 #: Kernel families the tuner knows tile heuristics for.
 KERNELS = ("gram", "gram_project", "featurize_gram", "eigproject",
@@ -174,8 +185,11 @@ def heuristic_blocks(kernel: str, **dims: int) -> dict:
                 "block_k": tile(dims["k"], 256, 2048),
                 "double_buffer": lowered}
     if kernel == "featurize_gram":
-        return {"block_n": tile(dims["n"], 512, 4096),
-                "double_buffer": lowered}
+        if not lowered:
+            return {"block_n": tile(dims["n"], 512, 4096),
+                    "double_buffer": False}
+        return {"block_n": _featurize_gram_block_n(**dims),
+                "double_buffer": True}
     if kernel == "eigproject":
         return {"block_d": tile(dims["d"], 256, 2048),
                 "block_k": tile(dims["k"], 256, 2048)}
@@ -194,6 +208,49 @@ def heuristic_blocks(kernel: str, **dims: int) -> dict:
     # assign: rows = arrival wave, lanes = flattened d*d directory axis
     return {"block_b": tile(dims["b"], 256, 1024),
             "block_d2": tile(dims["d2"], 512, 8192)}
+
+
+def featurize_gram_vmem_bytes(block_n: int, m: int, d: int,
+                              itemsize: int) -> int:
+    """VMEM the DMA-streamed ``featurize_gram`` kernel allocates: two
+    ``(block_n, m)`` row slots, the resident ``(m, d)`` projection, the
+    f32 ``(d, d)`` accumulator and output, and the projected ``(block_n,
+    d)`` tile (f32 accumulate plus its compute-dtype copy).  ``m``/``d``
+    are lane-padded as the wrapper pads them."""
+    m, d = _round_lane(m), _round_lane(d)
+    return (2 * block_n * m * itemsize + m * d * itemsize
+            + 2 * d * d * 4 + block_n * d * (4 + itemsize))
+
+
+def vmem_budget_bytes(kind: str | None = None) -> int:
+    """VMEM a lowered tile plan may fill on device ``kind`` (default: the
+    host's device 0): its scoped-VMEM limit less Mosaic's reserve.
+    Raises for a kind ``SCOPED_VMEM_BYTES`` does not list."""
+    kind = dispatch.device_kind() if kind is None else kind
+    limit = next((v for k, v in SCOPED_VMEM_BYTES.items()
+                  if k in kind.lower()), None)
+    if limit is None:
+        raise ValueError(f"no scoped-VMEM limit for device kind {kind!r}: "
+                         f"add it to SCOPED_VMEM_BYTES")
+    return limit - _VMEM_RESERVE
+
+
+def _featurize_gram_block_n(n: int, m: int, d: int, itemsize: int) -> int:
+    """Largest row tile (a multiple of 16, <= 512, no taller than the
+    rows) whose ``featurize_gram_vmem_bytes`` fits ``vmem_budget_bytes``.
+    Raises when even the smallest tile does not fit beside the resident
+    projection."""
+    budget = vmem_budget_bytes()
+    fixed = featurize_gram_vmem_bytes(0, m, d, itemsize)
+    per_row = featurize_gram_vmem_bytes(1, m, d, itemsize) - fixed
+    fit = (budget - fixed) // per_row
+    block = min(512, fit, -(-int(n) // _SUBLANE) * _SUBLANE)
+    block = block // _SUBLANE * _SUBLANE
+    if block < _SUBLANE:
+        raise ValueError(
+            f"featurize_gram: no row tile fits {budget} B of VMEM beside a "
+            f"resident ({m}, {d}) projection at itemsize {itemsize}")
+    return block
 
 
 def get_blocks(kernel: str, **dims: int) -> dict:

@@ -28,6 +28,7 @@ import jax
 from repro import optim
 from repro.configs.base import ARCH_IDS, INPUT_SHAPES, get_arch
 from repro.launch import mesh as ML
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch import roofline as RL
 from repro.launch import sharding as SH
 from repro.launch import steps as ST
@@ -161,6 +162,7 @@ def run_one(arch_id: str, shape_name: str, mesh_kind: str,
         collective_counts=coll_counts,
         collective_bytes_by_kind=m2["coll_bytes_by_kind"],
         model_flops_global=mf,
+        hw=RL.V5E,                 # the production mesh is a v5e pod
     )
 
     result = {
@@ -202,6 +204,7 @@ def _opt_specs(opt_shape, pspecs):
 
 
 def main() -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="one arch id (default all)")
     ap.add_argument("--shape", default=None, choices=list(INPUT_SHAPES))

@@ -34,7 +34,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import optim
@@ -237,8 +236,8 @@ def manual_loss_fn(cfg: ArchConfig, mesh: Mesh, dp_axes=("data",),
     in_specs = (pspecs,
                 P(dp_axes, None),        # tokens (replicated over model)
                 P(dp_axes, None))
-    fn = shard_map(fwd_loss, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(fwd_loss, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
 
     def loss(params, batch):
         return fn(params, batch["tokens"], batch["labels"])

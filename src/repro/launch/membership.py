@@ -58,6 +58,7 @@ import zlib
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import use_compile_cache
 
 SCENARIOS = ("clean", "label-noise", "byzantine", "drift")
 ARRIVAL_PATTERNS = ("steady", "bursty")
@@ -279,6 +280,7 @@ def run_cell(args, scenario: str, arrivals: str,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed-users", type=int, default=64)
     ap.add_argument("--seed-groups", type=int, default=0,

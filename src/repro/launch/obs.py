@@ -26,6 +26,7 @@ from pathlib import Path
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _quick_workload(outdir: Path) -> dict[str, Path]:
@@ -160,6 +161,7 @@ def cmd_profile(args) -> None:
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(prog="repro.launch.obs")
     sub = ap.add_subparsers(dest="cmd", required=True)
 

@@ -91,6 +91,10 @@ def main() -> None:
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     import jax
     import numpy as np
 

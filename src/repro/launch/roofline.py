@@ -24,10 +24,11 @@ Two consumers share this module:
   ``kernels/tuning.py`` tile heuristics.
 
 Hardware peaks live in ``HW_TABLE`` keyed by device kind (the
-``kernels.dispatch.device_kind()`` string), with a CPU entry so the
-interpret-mode host still gets a (rough) roof; unknown kinds fall back by
-platform.  ``peak_flops`` may be overridden per call (the
-``--peak-flops`` benchmark flag) for hosts whose kind string is missing.
+``kernels.dispatch.device_kind()`` string).  A kind that is not in the
+table is an error, never a default: a roof borrowed from another chip
+would be reported under that chip's name.  The CPU row only ranks tile
+plans in interpret mode and is never a device number.  ``peak_flops``
+may be overridden per call (the ``--peak-flops`` benchmark flag).
 """
 from __future__ import annotations
 
@@ -72,28 +73,18 @@ HW_TABLE: dict[str, HardwareSpec] = {
     "cpu": HardwareSpec("cpu", peak_flops=2e11, hbm_bw=50e9, link_bw=10e9),
 }
 
-# Backwards-compatible module constants (the original v5e-only model).
-PEAK_FLOPS = V5E.peak_flops
-HBM_BW = V5E.hbm_bw
-LINK_BW = V5E.link_bw
-
 
 def detect_hardware(peak_flops: float | None = None) -> HardwareSpec:
-    """The host device's ``HardwareSpec`` by device-kind substring match,
-    falling back to the platform name ("cpu"/"gpu"/"tpu"), then to the
-    v5e reference.  ``peak_flops`` overrides the matmul peak (the
-    ``--peak-flops`` flag for unlisted hosts)."""
-    kind = dispatch.device_kind().lower()
-    hw = None
-    for key, spec in HW_TABLE.items():
-        if key in kind:
-            hw = spec
-            break
+    """The host device's ``HardwareSpec`` by device-kind substring match.
+    Raises ``ValueError`` for a kind ``HW_TABLE`` does not list.
+    ``peak_flops`` overrides the matmul peak (the ``--peak-flops``
+    flag)."""
+    kind = dispatch.device_kind()
+    hw = next((spec for key, spec in HW_TABLE.items()
+               if key in kind.lower()), None)
     if hw is None:
-        platform = dispatch.backend_kind()
-        hw = HW_TABLE.get(platform, V5E)
-        if platform == "gpu" and "gpu" not in HW_TABLE:   # pragma: no cover
-            hw = HW_TABLE["a100"]
+        raise ValueError(f"no peaks for device kind {kind!r}: add it to "
+                         f"HW_TABLE with its source")
     if peak_flops is not None:
         hw = dataclasses.replace(hw, name=f"{hw.name}-custom",
                                  peak_flops=float(peak_flops))
@@ -175,7 +166,7 @@ class Roofline:
     collective_counts: dict[str, int]
     collective_bytes_by_kind: dict[str, int]
     model_flops_global: float
-    hw: HardwareSpec = V5E
+    hw: HardwareSpec
 
     @property
     def compute_term_s(self) -> float:
@@ -220,7 +211,7 @@ class Roofline:
 
 
 def analyze(compiled, chips: int, model_flops_global: float,
-            hw: HardwareSpec = V5E) -> Roofline:
+            hw: HardwareSpec) -> Roofline:
     cost = compiled.cost_analysis() or {}
     if isinstance(cost, list):     # some backends return [dict]
         cost = cost[0] if cost else {}

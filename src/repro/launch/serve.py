@@ -18,6 +18,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import get_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.decode_loop import (ClusterHeads, Request, ServeConfig,
                                       ServeEngine, cluster_logits_fn,
                                       greedy_decode)
@@ -41,6 +42,7 @@ def _make_requests(rng: np.random.Generator, n: int, vocab: int,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_1_7b")
     ap.add_argument("--reduced", type=int, default=1)

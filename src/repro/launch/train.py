@@ -20,10 +20,12 @@ from repro import obs, optim
 from repro.checkpoint import restore_checkpoint, save_checkpoint, latest_step
 from repro.configs.base import get_arch
 from repro.data import tokens as tok
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import get_model
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_1_7b")
     ap.add_argument("--reduced", type=int, default=1)

@@ -93,7 +93,8 @@ class TestRooflineParser:
                            hlo_bytes_per_device=819e9 * 2,
                            collective_bytes_per_device=50e9 * 3,
                            collective_counts={}, collective_bytes_by_kind={},
-                           model_flops_global=197e12 * 256 / 2)
+                           model_flops_global=197e12 * 256 / 2,
+                           hw=RL.V5E)
         assert roof.compute_term_s == pytest.approx(1.0)
         assert roof.memory_term_s == pytest.approx(2.0)
         assert roof.collective_term_s == pytest.approx(3.0)
@@ -103,6 +104,46 @@ class TestRooflineParser:
     def test_model_flops(self):
         assert RL.model_flops(10, 5, "train") == 300
         assert RL.model_flops(10, 5, "decode") == 100
+
+    def test_detect_hardware_by_kind(self, monkeypatch):
+        from repro.kernels import dispatch
+
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v5 lite")
+        assert RL.detect_hardware() is RL.V5E
+        custom = RL.detect_hardware(peak_flops=1e12)
+        assert custom.peak_flops == 1e12 and custom.hbm_bw == RL.V5E.hbm_bw
+
+    def test_detect_hardware_unknown_kind_raises(self, monkeypatch):
+        from repro.kernels import dispatch
+
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v99")
+        with pytest.raises(ValueError, match="no peaks for device kind"):
+            RL.detect_hardware()
+
+
+class TestCompileCache:
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache as cc
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(cc.ENV, str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_unset_env_uses_checkout_dir(self, monkeypatch):
+        from pathlib import Path
+
+        from repro.launch import compile_cache as cc
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv(cc.ENV, raising=False)
+        try:
+            got = cc.use_compile_cache()
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
 
 
 class TestOptim:

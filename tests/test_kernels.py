@@ -429,7 +429,8 @@ class TestTuning:
 
     def test_heuristics_cover_all_kernels(self):
         dims = {"gram": dict(n=300, d=70), "gram_project": dict(n=300, k=70),
-                "featurize_gram": dict(n=300), "eigproject": dict(d=70, k=9),
+                "featurize_gram": dict(n=300, m=96, d=40, itemsize=4),
+                "eigproject": dict(d=70, k=9),
                 "linkage": dict(n=256), "assign": dict(b=64, d2=1024),
                 "recurrent_scan": dict(s=96, d=70)}
         for kernel in tuning.KERNELS:
@@ -442,6 +443,44 @@ class TestTuning:
                     assert val >= 1, (kernel, k, val)
                     continue
                 assert val >= 1 and val % 128 == 0, (kernel, k, val)
+
+    @pytest.mark.parametrize("n,m,d,itemsize", [
+        (4096, 3072, 256, 4), (4096, 3072, 256, 2), (64, 3072, 128, 4),
+        (300, 784, 64, 4)])
+    def test_featurize_gram_lowered_plan_fits_vmem(self, monkeypatch, n, m,
+                                                   d, itemsize):
+        monkeypatch.setattr(dispatch, "supports_lowering", lambda: True)
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v5 lite")
+        budget = tuning.vmem_budget_bytes()
+        assert budget == (16 << 20) - (1 << 20)
+        blocks = tuning.heuristic_blocks("featurize_gram", n=n, m=m, d=d,
+                                         itemsize=itemsize)
+        bn = blocks["block_n"]
+        assert blocks["double_buffer"] is True
+        assert bn % 16 == 0 and bn <= 512
+        assert tuning.featurize_gram_vmem_bytes(bn, m, d, itemsize) <= budget
+        # the tile is as large as the budget (or the rows) allow
+        bigger = bn + 16
+        assert (bigger > 512 or bigger > n + (-n % 16)
+                or tuning.featurize_gram_vmem_bytes(
+                    bigger, m, d, itemsize) > budget)
+
+    def test_featurize_gram_lowered_plan_rejects_oversized_w(self,
+                                                             monkeypatch):
+        monkeypatch.setattr(dispatch, "supports_lowering", lambda: True)
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v5 lite")
+        with pytest.raises(ValueError, match="no row tile fits"):
+            tuning.heuristic_blocks("featurize_gram", n=512, m=16384,
+                                    d=512, itemsize=4)
+
+    @pytest.mark.parametrize("kind", ["TPU v99", "cpu"])
+    def test_featurize_gram_lowered_plan_rejects_unknown_kind(
+            self, monkeypatch, kind):
+        monkeypatch.setattr(dispatch, "supports_lowering", lambda: True)
+        monkeypatch.setattr(dispatch, "device_kind", lambda: kind)
+        with pytest.raises(ValueError, match="no scoped-VMEM limit"):
+            tuning.heuristic_blocks("featurize_gram", n=512, m=3072,
+                                    d=256, itemsize=4)
 
     def test_unknown_kernel_rejected(self):
         with pytest.raises(ValueError, match="unknown kernel"):
