@@ -212,11 +212,15 @@ def _raw_finish(grams, top_k, eig_floor, impl, eig, iters, oversample,
     (subspace iteration by default — no O(d^3) eigh) + relevance +
     symmetrize.  The per-user eigen-residual is only computed when the
     caller will ``check`` it (``resid`` is ``None`` otherwise)."""
-    lam, v = sig.topk_spectrum(grams, top_k, method=eig, iters=iters,
-                               oversample=oversample)
-    resid = sig.subspace_residual(grams, lam, v) if check else None
-    r = sim.relevance_matrix(grams, lam, v, eig_floor, impl=impl)
-    return r, sim.symmetrize(r), resid, lam, v
+    with jax.named_scope("spectrum"):
+        lam, v = sig.topk_spectrum(grams, top_k, method=eig, iters=iters,
+                                   oversample=oversample)
+        resid = sig.subspace_residual(grams, lam, v) if check else None
+    with jax.named_scope("relevance"):
+        r = sim.relevance_matrix(grams, lam, v, eig_floor, impl=impl)
+    with jax.named_scope("symmetrize"):
+        big_r = sim.symmetrize(r)
+    return r, big_r, resid, lam, v
 
 
 def _sharded_raw_protocol(x, nv, *, axis: str, engine, top_k: int,

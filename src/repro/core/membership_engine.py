@@ -410,6 +410,17 @@ def _proto_update(protos, counts, delta, m, *, sign: float):
                      jnp.zeros_like(upd)), new_counts
 
 
+def _to_host(x, what: str, dtype=None) -> np.ndarray:
+    """A blocking device->host read of the lifecycle paths: exactly
+    ``np.asarray(x, dtype)`` with telemetry off; with it on, a read of a
+    device array runs in a ``membership.host_read`` span naming ``what``
+    was read, so a trace counts the reads that pace a wave."""
+    if not obs.enabled() or not isinstance(x, jax.Array):
+        return np.asarray(x, dtype)
+    with obs.span("membership.host_read", what=what):
+        return np.asarray(x, dtype)
+
+
 # Canonical home is ``core.similarity`` (the hierarchy global stage uses
 # it too); re-exported here because it is directory-serving API surface.
 signature_relevance = sim.signature_relevance
@@ -577,7 +588,6 @@ class MembershipEngine:
         ``v (B, d, k)``.  One dispatch per wave on the device backends.
         """
         st = self._require_state()
-        t0 = obs.now()
         with obs.span("membership.assign", backend=self.cfg.backend) as sp:
             res = self._assign(st, v)
             # labels alone gate the whole one-dispatch wave program, so
@@ -585,7 +595,6 @@ class MembershipEngine:
             # paying three separate readiness walks
             sp.sync(res.labels)
         if obs.enabled():
-            obs.observe("assign_latency_us", (obs.now() - t0) * 1e6)
             obs.count("membership.assign_waves")
             # compare on the host: a jnp == here would be a full jax
             # dispatch per wave, dwarfing the rest of the telemetry
@@ -671,7 +680,7 @@ class MembershipEngine:
 
     def _free_slots(self, n: int) -> np.ndarray:
         st = self._require_state()
-        free = np.flatnonzero(~np.asarray(st.valid))
+        free = np.flatnonzero(~_to_host(st.valid, "valid"))
         if len(free) < n:
             raise ValueError(
                 f"directory full: {n} arrivals but only {len(free)} free "
@@ -697,9 +706,9 @@ class MembershipEngine:
 
     def _admit(self, lam, v, labels) -> np.ndarray:
         st = self._require_state()
-        lam = np.asarray(lam, np.float32)
+        lam = _to_host(lam, "lam", np.float32)
         slots = self._free_slots(lam.shape[0])
-        labels = np.asarray(labels, np.int32)
+        labels = _to_host(labels, "labels", np.int32)
         streaming = self.cfg.aggregator == "mean"
         if self.on_device:
             v_w = jnp.asarray(v, jnp.float32)
@@ -722,7 +731,7 @@ class MembershipEngine:
                 st, lam=lam_t, v=v_t, labels=lab_t, valid=valid,
                 protos=table, counts=counts, proto_scales=scales)
             return slots
-        v = np.asarray(v, np.float32)
+        v = _to_host(v, "v", np.float32)
         lam_t, v_t = st.lam.copy(), st.v.copy()
         lab_t, valid = st.labels.copy(), st.valid.copy()
         lam_t[slots], v_t[slots], lab_t[slots], valid[slots] = \
@@ -754,16 +763,16 @@ class MembershipEngine:
 
     def _evict(self, slots) -> None:
         st = self._require_state()
-        slots = np.asarray(slots, np.int32)
+        slots = _to_host(slots, "slots", np.int32)
         if len(np.unique(slots)) != len(slots):
             # a repeated slot would down-date the prototype twice for one
             # departure, silently corrupting the streaming mean
             raise ValueError(f"duplicate slots in evict: {slots.tolist()}")
-        occupied = np.asarray(st.valid)[slots]
+        occupied = _to_host(st.valid, "valid")[slots]
         if not occupied.all():
             raise ValueError(f"evicting empty slots "
                              f"{slots[~occupied].tolist()}")
-        labels_out = np.asarray(st.labels)[slots]
+        labels_out = _to_host(st.labels, "labels")[slots]
         streaming = self.cfg.aggregator == "mean"
         if self.on_device:
             sl = jnp.asarray(slots)
@@ -820,24 +829,31 @@ class MembershipEngine:
         under ``drift_stat="median"`` (one poisoned prototype then
         cannot trip re-cluster thrash on its own)."""
         st = self._require_state()
-        n = max(st.n_members, 1)
-        p = np.asarray(quant.dequantize_directory(st.protos,
-                                                  st.proto_scales))
-        p0 = np.asarray(quant.dequantize_directory(st.protos0,
-                                                   st.proto0_scales))
-        shift = np.linalg.norm((p - p0).reshape(st.n_clusters, -1), axis=1)
-        base = np.maximum(
-            np.linalg.norm(p0.reshape(st.n_clusters, -1), axis=1), 1e-6)
-        rel = shift / base
-        stat = (np.median(rel) if self.cfg.drift_stat == "median"
-                else rel.max())
-        stats = {
-            "unassigned_frac": st.n_unassigned / n,
-            "proto_shift": float(stat),
-            "proto_shift_max": float(rel.max()),
-            "n_members": st.n_members,
-            "n_reclusters": st.n_reclusters,
-        }
+        with obs.span("membership.drift_stats"):
+            valid = _to_host(st.valid, "valid")
+            labels = _to_host(st.labels, "labels")
+            n_members = int(valid.sum())
+            n_unassigned = int((valid & (labels < 0)).sum())
+            p = _to_host(quant.dequantize_directory(st.protos,
+                                                    st.proto_scales),
+                         "protos")
+            p0 = _to_host(quant.dequantize_directory(st.protos0,
+                                                     st.proto0_scales),
+                          "protos0")
+            shift = np.linalg.norm((p - p0).reshape(st.n_clusters, -1),
+                                   axis=1)
+            base = np.maximum(
+                np.linalg.norm(p0.reshape(st.n_clusters, -1), axis=1), 1e-6)
+            rel = shift / base
+            stat = (np.median(rel) if self.cfg.drift_stat == "median"
+                    else rel.max())
+            stats = {
+                "unassigned_frac": n_unassigned / max(n_members, 1),
+                "proto_shift": float(stat),
+                "proto_shift_max": float(rel.max()),
+                "n_members": n_members,
+                "n_reclusters": st.n_reclusters,
+            }
         if obs.enabled():
             obs.gauge("unassigned_frac", stats["unassigned_frac"])
             obs.gauge("proto_shift", stats["proto_shift"])

@@ -348,9 +348,11 @@ class SignatureEngine:
             # inside a shard_map/jit trace: spans are host-side and would
             # record trace time, not run time — instrument nothing here
             return self._accumulate_grams(raw, nv, assume_full)
+        n = raw.shape[1]
+        chunk = min(self.cfg.chunk_rows or n, n)
         with obs.span("signature.accumulate_grams",
-                      n_users=raw.shape[0],
-                      backend=self.cfg.backend) as sp:
+                      n_users=raw.shape[0], backend=self.cfg.backend,
+                      chunks=-(-n // max(chunk, 1))) as sp:
             return sp.sync(self._accumulate_grams(raw, nv, assume_full))
 
     def _accumulate_grams(self, raw, nv: jax.Array,

@@ -228,11 +228,13 @@ def _round_body(p_stack, g, x, y, n_per, uids, mask, dkeys, cluster_w,
 
         return jax.vmap(per_cluster)(p, dkeys, x, y, n_per, uids, mask)
 
-    p_stack, losses = jax.lax.scan(local_round, p_stack,
-                                   jnp.arange(local_rounds))
+    with jax.named_scope("local_rounds"):
+        p_stack, losses = jax.lax.scan(local_round, p_stack,
+                                       jnp.arange(local_rounds))
     mean_loss = jnp.mean(losses, axis=0)                     # (T,)
-    p_stack = hier.gps_aggregate_stacked(p_stack, cluster_w, is_common,
-                                         axis=axis)
+    with jax.named_scope("gps_aggregate"):
+        p_stack = hier.gps_aggregate_stacked(p_stack, cluster_w, is_common,
+                                             axis=axis)
     return p_stack, mean_loss
 
 
@@ -254,10 +256,20 @@ def _run_scanned(p_stack, x, y, n_per, uids, mask, dkeys, cluster_w,
 _STATICS = ("loss_fn", "optimizer", "clip_norm", "steps", "batch_size",
             "local_rounds", "is_common")
 
-_fused_global_round = partial(jax.jit, static_argnames=_STATICS)(
-    partial(_round_body, axis=None))
-_fused_run = partial(jax.jit, static_argnames=_STATICS + ("global_rounds",))(
-    partial(_run_scanned, axis=None))
+# Named wrappers, not ``partial``s, so the compiled programs carry their
+# own names (``jit__fused_global_round``, ``jit__fused_run``) in profiles.
+@partial(jax.jit, static_argnames=_STATICS)
+def _fused_global_round(p_stack, g, x, y, n_per, uids, mask, dkeys,
+                        cluster_w, part_rate, **statics):
+    return _round_body(p_stack, g, x, y, n_per, uids, mask, dkeys,
+                       cluster_w, part_rate, axis=None, **statics)
+
+
+@partial(jax.jit, static_argnames=_STATICS + ("global_rounds",))
+def _fused_run(p_stack, x, y, n_per, uids, mask, dkeys, cluster_w,
+               part_rate, **statics):
+    return _run_scanned(p_stack, x, y, n_per, uids, mask, dkeys, cluster_w,
+                        part_rate, axis=None, **statics)
 
 
 @functools.lru_cache(maxsize=64)
@@ -319,21 +331,24 @@ def _train_fused(users, labels, models, eval_sets, cfg: MTHFLConfig,
     n_stack = jnp.ones((n_clusters, c_max), jnp.float32  # pads: n=1, masked
                        ).at[rows, slot].set(n_all)
 
-    x_np = np.zeros((n_clusters, c_max, n_max) + tuple(sample_shape),
-                    np.float32)
-    y_np = np.zeros((n_clusters, c_max, n_max), np.int32)
-    for t in range(n_clusters):
-        for c, ((x, y), n) in enumerate(zip(setup.datasets[t],
-                                            setup.n_samples[t])):
-            x_np[t, c, :n] = x
-            y_np[t, c, :n] = y
+    with obs.span("trainer.restack") as sp:
+        x_np = np.zeros((n_clusters, c_max, n_max) + tuple(sample_shape),
+                        np.float32)
+        y_np = np.zeros((n_clusters, c_max, n_max), np.int32)
+        for t in range(n_clusters):
+            for c, ((x, y), n) in enumerate(zip(setup.datasets[t],
+                                                setup.n_samples[t])):
+                x_np[t, c, :n] = x
+                y_np[t, c, :n] = y
 
-    p_stack = jax.tree.map(lambda *ls: jnp.stack(ls), *lps_params)
-    data = dict(x=jnp.asarray(x_np), y=jnp.asarray(y_np),
-                n_per=n_stack, uids=uid_stack,
-                mask=mask,
-                dkeys=jnp.stack(setup.data_keys),
-                cluster_w=jnp.asarray(setup.cluster_weights, jnp.float32))
+        p_stack = jax.tree.map(lambda *ls: jnp.stack(ls), *lps_params)
+        data = dict(x=jnp.asarray(x_np), y=jnp.asarray(y_np),
+                    n_per=n_stack, uids=uid_stack,
+                    mask=mask,
+                    dkeys=jnp.stack(setup.data_keys),
+                    cluster_w=jnp.asarray(setup.cluster_weights,
+                                          jnp.float32))
+        sp.sync(data["x"])
     statics = dict(loss_fn=models[0].loss_fn,
                    optimizer=fed_client._make_opt(cfg.client),
                    clip_norm=cfg.client.clip_norm, steps=cfg.local_steps,
@@ -378,13 +393,14 @@ def _train_fused(users, labels, models, eval_sets, cfg: MTHFLConfig,
     empty = [not setup.members[t] for t in range(n_clusters)]
 
     def eval_round(g, stack):
-        for t in range(n_clusters):
-            if empty[t]:
-                acc_hist[g, t] = np.nan
-                continue
-            p_t = jax.tree.map(lambda l: l[t], stack)
-            ex, ey = eval_sets[t]
-            acc_hist[g, t] = models[t].accuracy(p_t, ex, ey)
+        with obs.span("trainer.eval"):
+            for t in range(n_clusters):
+                if empty[t]:
+                    acc_hist[g, t] = np.nan
+                    continue
+                p_t = jax.tree.map(lambda l: l[t], stack)
+                ex, ey = eval_sets[t]
+                acc_hist[g, t] = models[t].accuracy(p_t, ex, ey)
 
     if cfg.scan_rounds:
         with obs.span("trainer.scan_rounds",
@@ -397,8 +413,12 @@ def _train_fused(users, labels, models, eval_sets, cfg: MTHFLConfig,
     else:
         with obs.span("trainer.rounds", rounds=cfg.global_rounds) as sp:
             for g in range(cfg.global_rounds):
-                p_stack, loss = round_fn(p_stack, jnp.asarray(g, jnp.int32),
-                                         *args)
+                # the loss is read after the span closes, so the span's
+                # wait_us is the device wait of the round
+                with obs.span("trainer.round") as rsp:
+                    p_stack, loss = round_fn(
+                        p_stack, jnp.asarray(g, jnp.int32), *args)
+                    rsp.sync(loss)
                 loss_hist[g] = np.asarray(loss)[:n_clusters]
                 eval_round(g, p_stack)
             sp.sync(p_stack)
@@ -508,27 +528,29 @@ def train_mthfl(users: Sequence,                      # list[UserData-like]
     if not 0.0 <= cfg.dropout_frac < 1.0:
         raise ValueError("cfg.dropout_frac must be in [0, 1), got "
                          f"{cfg.dropout_frac!r}")
-    setup = _setup_clusters(users, labels, n_clusters, cfg.seed,
-                            cluster_classes)
-    lps_params = [models[t].init(setup.init_keys[t])
-                  for t in range(n_clusters)]
+    with obs.span("trainer.train_mthfl", backend=cfg.backend,
+                  rounds=cfg.global_rounds) as sp:
+        with obs.span("trainer.setup") as ssp:
+            setup = _setup_clusters(users, labels, n_clusters, cfg.seed,
+                                    cluster_classes)
+            lps_params = ssp.sync([models[t].init(setup.init_keys[t])
+                                   for t in range(n_clusters)])
 
-    can_fuse = _stackable(lps_params)
-    if fused == "auto":
-        use_fused = can_fuse
-    elif fused:
-        if not can_fuse:
-            raise ValueError(
-                "fused=True requires every cluster's params to stack — "
-                "same structure, shapes and dtypes (got heterogeneous "
-                "models); use fused='auto' to fall back to the reference "
-                "loop")
-        use_fused = True
-    else:
-        use_fused = False
+        can_fuse = _stackable(lps_params)
+        if fused == "auto":
+            use_fused = can_fuse
+        elif fused:
+            if not can_fuse:
+                raise ValueError(
+                    "fused=True requires every cluster's params to stack — "
+                    "same structure, shapes and dtypes (got heterogeneous "
+                    "models); use fused='auto' to fall back to the "
+                    "reference loop")
+            use_fused = True
+        else:
+            use_fused = False
+        sp.note(fused=use_fused)
 
-    with obs.span("trainer.train_mthfl", fused=use_fused,
-                  backend=cfg.backend, rounds=cfg.global_rounds):
         if use_fused:
             hist = _train_fused(users, labels, models, eval_sets, cfg,
                                 setup, lps_params, mesh)

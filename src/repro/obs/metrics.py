@@ -16,9 +16,9 @@ Stack-wide metrics fed from the instrumented hot paths:
       via the jit-cache-miss hook: a ``jax.monitoring`` duration
       listener on ``/jax/core/compile/jaxpr_trace_duration``, which
       fires exactly once per jit trace (= compilation-cache miss).
-  ``assign_latency_us`` / ``directory_bytes`` / ``unassigned_frac`` /
-  ``recluster_events``
-      from ``MembershipEngine``.
+  ``directory_bytes`` / ``unassigned_frac`` / ``recluster_events``
+      from ``MembershipEngine`` (an assign wave's time is its
+      ``membership.assign`` span).
   ``comm_upload_bytes`` + the full ``comm.*`` mirror
       fed straight from ``CommLedger.summary()`` via ``record_ledger``.
 """

@@ -6,8 +6,10 @@ timing.
 
 At span exit the registered values are ``jax.block_until_ready``-ed
 before the clock is read, so ``dur_us`` measures device work, not just
-async dispatch latency.  Pass ``sync=False`` (or register nothing) for
-async paths where blocking would serialize a pipeline.
+async dispatch latency.  The time spent in that block is recorded as
+``wait_us`` (only on spans that registered values), so ``dur_us -
+wait_us`` is the span's host part.  Pass ``sync=False`` (or register
+nothing) for async paths where blocking would serialize a pipeline.
 
 Spans nest per-thread via a thread-local stack; completed spans append
 to one process-global record list exported as JSONL (``save_trace``) or
@@ -98,8 +100,11 @@ class Span:
         return self
 
     def __exit__(self, *exc):
+        wait = None
         if self._vals:
+            tw = core.now()
             jax.block_until_ready(self._vals)
+            wait = core.now() - tw
         t1 = core.now()
         if self._annot is not None:
             self._annot.__exit__(*exc)
@@ -113,6 +118,8 @@ class Span:
             "ts_us": round((self.t0 - core.epoch()) * 1e6, 3),
             "dur_us": round((t1 - self.t0) * 1e6, 3),
         }
+        if wait is not None:
+            rec["wait_us"] = round(wait * 1e6, 3)
         if self.meta:
             rec["meta"] = {k: _jsonable(v) for k, v in self.meta.items()}
         with _lock:

@@ -75,6 +75,23 @@ class TestSpans:
         rec = obs.trace_records()[-1]
         assert rec["name"] == "compute" and rec["dur_us"] > 0
 
+    def test_wait_us_on_a_synced_span(self):
+        with obs.scope(True):
+            with obs.span("compute") as sp:
+                sp.sync(jnp.ones((256, 256)) @ jnp.ones((256, 256)))
+        rec = obs.trace_records()[-1]
+        assert "wait_us" in rec
+        assert 0.0 <= rec["wait_us"] <= rec["dur_us"]
+
+    def test_no_wait_us_without_registered_values(self):
+        with obs.scope(True):
+            with obs.span("host_only"):
+                float(jnp.ones(8).sum())
+            with obs.span("unsynced", sync=False) as sp:
+                sp.sync(jnp.ones(8))
+        for rec in obs.trace_records():
+            assert "wait_us" not in rec, rec["name"]
+
     def test_note_attaches_meta(self):
         with obs.scope(True):
             with obs.span("s") as sp:
@@ -222,6 +239,26 @@ class TestDisabledMode:
         assert snap == {"counters": {}, "gauges": {}, "histograms": {}}
         assert obs.events() == []
 
+    def test_new_spans_record_nothing_and_read_no_clock(
+            self, oneshot_result, monkeypatch):
+        """The lifecycle's host reads and a fused training job, with
+        telemetry off: no span, and no clock read by the obs layer."""
+        from repro.obs import core
+
+        def no_clock():
+            raise AssertionError("obs read the clock while disabled")
+
+        monkeypatch.setattr(core, "now", no_clock)
+        monkeypatch.setattr(obs, "now", no_clock)
+        eng, lam, v = _device_wave(oneshot_result)
+        slots = eng.admit(lam, v, eng.assign(lam, v).labels)
+        eng.evict(slots[:2])
+        eng.drift_stats()
+        _tiny_fused_job()
+        assert obs.trace_records() == []
+        assert obs.snapshot() == {"counters": {}, "gauges": {},
+                                  "histograms": {}}
+
     def test_scope_restores_prior_state(self):
         assert not obs.enabled()
         with obs.scope(True):
@@ -296,6 +333,47 @@ def oneshot_result():
     return one_shot_clustering(feats, 2)
 
 
+def _device_wave(res):
+    """A jnp directory seeded from ``res`` and a 4-newcomer wave held on
+    the device, as a serving loop holds its uploads."""
+    eng = MembershipEngine.from_oneshot(
+        res, MembershipConfig(backend="jnp", capacity=32))
+    return eng, jnp.asarray(res.lam)[:4], jnp.asarray(res.v)[:4]
+
+
+ROUNDS = 3
+
+
+def _tiny_fused_job(scan_rounds=False):
+    """A fused MT-HFL job of ROUNDS rounds: 2 clusters of 2 MLP users."""
+    from repro.data.partition import UserData
+    from repro.fed import client as fclient
+    from repro.fed import partition as fpart
+    from repro.fed import trainer as ftrainer
+    from repro.models import mlp
+
+    rng = np.random.default_rng(0)
+    mcfg = mlp.PaperMLPConfig(m=6, hidden=4, n_classes=2)
+    users = [UserData(user_id=i, task_id=i // 2,
+                      x=rng.normal(size=(12, 6)).astype(np.float32),
+                      y=rng.integers(0, 2, 12).astype(np.int32),
+                      task_classes=(0, 1)) for i in range(4)]
+    model = ftrainer.TaskModel(
+        init=lambda k: mlp.init(mcfg, k), loss_fn=mlp.loss_fn(mcfg),
+        accuracy=lambda p, x, y: mlp.accuracy(mcfg, p, x, y),
+        is_common=fpart.prefix_predicate(mlp.COMMON_PREFIXES))
+    evals = [(rng.normal(size=(8, 6)).astype(np.float32),
+              rng.integers(0, 2, 8).astype(np.int32))] * 2
+    cfg = ftrainer.MTHFLConfig(global_rounds=ROUNDS, local_rounds=1,
+                               local_steps=2, batch_size=4,
+                               client=fclient.ClientConfig(lr=0.1),
+                               scan_rounds=scan_rounds)
+    return ftrainer.train_mthfl(users, np.asarray([0, 0, 1, 1]),
+                                [model, model], evals, cfg,
+                                cluster_classes=[[0, 1], [0, 1]],
+                                fused=True)
+
+
 class TestInstrumentation:
     def test_pipeline_emits_all_three_pillars(self, oneshot_result):
         obs.reset()
@@ -314,12 +392,90 @@ class TestInstrumentation:
         assert obs.counter_value("membership.admits") == 4   # members
         assert obs.gauge_value("directory_bytes") > 0
         assert obs.gauge_value("unassigned_frac") is not None
-        snap = obs.snapshot()
-        assert snap["histograms"]["assign_latency_us"]["count"] == 1
+        assign = [r for r in obs.trace_records()
+                  if r["name"] == "membership.assign"]
+        assert len(assign) == 1
+        assert 0.0 <= assign[0]["wait_us"] <= assign[0]["dur_us"]
         kinds = [e["kind"] for e in obs.events()]
         assert kinds == ["seed", "assign_wave", "admit"]
         wave_ev = obs.events("assign_wave")[0]
         assert wave_ev["n"] == 4
+
+    def test_trainer_spans_per_round_and_per_job(self):
+        with obs.scope(True):
+            _tiny_fused_job()
+        recs = obs.trace_records()
+        by = {}
+        for r in recs:
+            by.setdefault(r["name"], []).append(r)
+        assert len(by["trainer.round"]) == ROUNDS
+        assert len(by["trainer.eval"]) == ROUNDS
+        assert len(by["trainer.restack"]) == 1
+        assert len(by["trainer.setup"]) == 1
+        job = by["trainer.train_mthfl"][0]
+        assert job["meta"]["fused"] is True
+        rounds_id = by["trainer.rounds"][0]["id"]
+        for r in by["trainer.round"] + by["trainer.eval"]:
+            assert r["parent"] == rounds_id
+        for r in by["trainer.setup"] + by["trainer.restack"]:
+            assert r["parent"] == job["id"]
+        for r in by["trainer.round"] + by["trainer.restack"]:
+            assert 0.0 <= r["wait_us"] <= r["dur_us"]
+
+    def test_scanned_job_evaluates_every_round(self):
+        with obs.scope(True):
+            _tiny_fused_job(scan_rounds=True)
+        names = [r["name"] for r in obs.trace_records()]
+        assert names.count("trainer.eval") == ROUNDS
+        assert names.count("trainer.scan_rounds") == 1
+
+    def test_fused_programs_carry_their_names(self, monkeypatch):
+        """The fused round and the scanned run compile under their own
+        names, so a profile names them (not ``jit__unknown``)."""
+        from repro.fed import trainer as ftrainer
+
+        seen = {}
+        for attr in ("_fused_global_round", "_fused_run"):
+            orig = getattr(ftrainer, attr)
+
+            def spy(*args, _orig=orig, _attr=attr, **kw):
+                seen[_attr] = _orig.lower(*args, **kw).as_text()
+                return _orig(*args, **kw)
+
+            monkeypatch.setattr(ftrainer, attr, spy)
+        _tiny_fused_job()
+        _tiny_fused_job(scan_rounds=True)
+        assert "module @jit__fused_global_round" in seen[
+            "_fused_global_round"]
+        assert "module @jit__fused_run" in seen["_fused_run"]
+
+    def test_lifecycle_host_reads_named(self, oneshot_result):
+        """One admit -> evict -> drift_stats cycle: every blocking device
+        read is a ``membership.host_read`` span under its lifecycle
+        span, named by what it read."""
+        eng, lam, v = _device_wave(oneshot_result)
+        labels = eng.assign(lam, v).labels
+        obs.reset()
+        with obs.scope(True):
+            slots = eng.admit(lam, v, labels)
+            eng.evict(slots[:2])
+            stats = eng.drift_stats()
+        recs = obs.trace_records()
+        ids = {r["id"]: r["name"] for r in recs}
+        reads = {}
+        for r in recs:
+            if r["name"] == "membership.host_read":
+                reads.setdefault(ids[r["parent"]], []).append(
+                    r["meta"]["what"])
+        assert reads == {
+            "membership.admit": ["lam", "valid", "labels"],
+            "membership.evict": ["valid", "labels"],
+            "membership.drift_stats": ["valid", "labels", "protos",
+                                       "protos0"]}
+        for r in recs:
+            if r["name"] in ("membership.admit", "membership.evict"):
+                assert 0.0 <= r["wait_us"] <= r["dur_us"]
+        assert stats["n_members"] == len(oneshot_result.labels) + 2
 
     def test_oneshot_records_ledger_and_spans(self):
         rng = np.random.default_rng(1)
