@@ -62,7 +62,7 @@ DEFAULT_BLOCKS = {
     "gram_project": {"block_n": 128, "block_k": 128,
                      "double_buffer": False},
     "featurize_gram": {"block_n": 128, "double_buffer": False},
-    "eigproject": {"block_d": 128, "block_k": 128},
+    "eigproject": {"block_u": 1, "block_c": 128},
     "linkage": {"block": 128},
     # pre-tuning chunking for the serving recurrences (bench_serve)
     "recurrent_scan": {"chunk": 16, "block_d": 128},
@@ -177,8 +177,7 @@ def _bench_eigproject(rng, quick, tune, records):
     v = jnp.asarray(rng.standard_normal((d, k)), jnp.float32)
     return _bench_family(
         "eigproject", f"{d}x{k}", lambda: project_norms_ref(g, v),
-        lambda blk: proj_ops.project_norms(g, v, block_d=blk["block_d"],
-                                           block_k=blk["block_k"]),
+        lambda blk: proj_ops.project_norms(g, v, block_c=blk["block_c"]),
         dict(d=d, k=k), tune, records)
 
 

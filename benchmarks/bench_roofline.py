@@ -85,7 +85,7 @@ def _kernel_cases(rng, quick: bool) -> list[dict]:
         dict(kernel="eigproject", tune_dims=dict(d=d, k=k),
              cost_dims=dict(d=d, k=k), itemsize=4,
              run=lambda blk: proj_ops.project_norms(
-                 g, v, block_d=blk["block_d"], block_k=blk["block_k"])),
+                 g, v, block_c=blk["block_c"])),
         dict(kernel="linkage", tune_dims=dict(n=nl),
              cost_dims=dict(n=nl), itemsize=4,
              run=lambda blk: link_ops.linkage_step(

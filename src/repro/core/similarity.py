@@ -226,16 +226,12 @@ def user_signature(features: jax.Array, cfg: SimilarityConfig,
 # Step 3: cross-projection (Eq. 2)
 # ---------------------------------------------------------------------------
 
-def cross_project(g_own: jax.Array, v_other: jax.Array,
-                  *, impl: str = "jnp") -> jax.Array:
+def cross_project(g_own: jax.Array, v_other: jax.Array) -> jax.Array:
     """``lamhat_k = || G_i v_k^{(j)} ||_2`` for each eigenvector column.
 
-    ``g_own (d, d)``, ``v_other (d, k)`` -> ``(k,)``.
+    ``g_own (d, d)``, ``v_other (d, k)`` -> ``(k,)``.  The Pallas form
+    projects a whole Gram stack at once (``relevance_matrix``).
     """
-    if impl == "pallas":
-        from repro.kernels.eigproject import ops as proj_ops
-
-        return proj_ops.project_norms(g_own, v_other)
     proj = g_own @ v_other                      # (d, k)
     return jnp.sqrt(jnp.sum(proj * proj, axis=0))
 
@@ -270,11 +266,25 @@ def relevance_matrix(grams: jax.Array, lams: jax.Array, vs: jax.Array,
     ``r[i, j]`` is user *i*'s estimate of its relevance to user *j*
     (projects j's eigenvectors through i's Gram, compares against i's own
     spectrum — paper Algorithm 2 lines 7-12).
+
+    ``impl="pallas"`` projects every Gram against the whole ``(d, N * k)``
+    signature table in one ``kernels.eigproject`` call; ``grams`` may hold
+    B != N rows (a device's local users against the gathered table), and
+    ``r`` is then ``(B, N)``.
     """
+    if impl == "pallas":
+        from repro.kernels.eigproject import ops as proj_ops
+
+        n, d, k = vs.shape
+        v_table = vs.transpose(1, 0, 2).reshape(d, n * k)
+        lam_hat = proj_ops.project_norms_table(grams, v_table)
+        lam_hat = lam_hat.reshape(grams.shape[0], n, k)
+        return jax.vmap(lambda lam_i, lh_i: jax.vmap(
+            lambda lh: relevance(lam_i, lh, eig_floor))(lh_i))(lams, lam_hat)
 
     def row(g_i, lam_i):
         def one(v_j):
-            lam_hat = cross_project(g_i, v_j, impl=impl)
+            lam_hat = cross_project(g_i, v_j)
             return relevance(lam_i, lam_hat, eig_floor)
 
         return jax.vmap(one)(vs)

@@ -1,1 +1,1 @@
-from repro.kernels.eigproject.ops import project_norms
+from repro.kernels.eigproject.ops import project_norms, project_norms_table

@@ -1,15 +1,16 @@
-"""Fused Gram-projection + column-norm kernel (paper Eq. 2).
+"""Gram-stack x signature-table projection norms (paper Eq. 2).
 
-Computes ``lamhat_k = || G v_k ||_2`` for all k eigenvector columns in one
-pass: grid = (k/bk, d/bd_row, d/bd_in); each step multiplies a (bd_row,
-bd_in) tile of G with a (bd_in, bk) tile of V into an fp32 row-block
-accumulator; when a row-block's inner reduction completes, its squared
-values are added to the per-column sum-of-squares accumulator, and the
-final step writes ``sqrt``.  The (d, bk) intermediate ``G @ V`` never
-round-trips to HBM — that is the fusion win over the two-op jnp form.
-
-Grid order: k-block outermost, then row-blocks, inner-dim innermost, so
-both accumulators are live for one (k-block) at a time.
+Computes ``out[b, c] = || G_b v_c ||_2`` for a stack of B Grams against
+every column of one ``(d, C)`` signature table (the N users' top-k
+eigenvectors side by side, ``C = N * k``) in one ``pallas_call``:
+grid = (B / block_u, C / block_c), column tiles innermost so a block of
+Grams stays resident while the table streams past it.  Each step views
+its ``(block_u, d, d)`` Grams as one ``(block_u * d, d)`` matrix,
+multiplies it by a ``(d, block_c)`` column tile on the MXU with fp32
+accumulation, squares and reduces over d in VMEM, and writes a
+``(block_u, block_c)`` tile of norms.  The ``(B, d, C)`` product never
+reaches HBM, and the columns are the flattened table, so a small k wastes
+no lanes.
 """
 from __future__ import annotations
 
@@ -18,62 +19,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(g_ref, v_ref, o_ref, prod_acc, sq_acc, *, n_row: int,
-            n_inner: int):
-    r = pl.program_id(1)
-    c = pl.program_id(2)
-
-    @pl.when((r == 0) & (c == 0))
-    def _init_sq():
-        sq_acc[...] = jnp.zeros_like(sq_acc)
-
-    @pl.when(c == 0)
-    def _init_prod():
-        prod_acc[...] = jnp.zeros_like(prod_acc)
-
-    prod_acc[...] += jax.lax.dot_general(
-        g_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(c == n_inner - 1)
-    def _accum_sq():
-        sq_acc[...] += jnp.sum(jnp.square(prod_acc[...]), axis=0,
-                               keepdims=True)
-
-    @pl.when((r == n_row - 1) & (c == n_inner - 1))
-    def _flush():
-        o_ref[...] = jnp.sqrt(sq_acc[...]).astype(o_ref.dtype)
+def _kernel(g_ref, v_ref, o_ref):
+    bu, d, _ = g_ref.shape
+    prod = jax.lax.dot_general(
+        g_ref[...].reshape(bu * d, d), v_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)               # (bu * d, bc)
+    sq = jnp.square(prod).reshape(bu, d, prod.shape[-1])
+    o_ref[...] = jnp.sqrt(jnp.sum(sq, axis=1)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "block_k",
+@functools.partial(jax.jit, static_argnames=("block_u", "block_c",
                                              "interpret"))
-def project_norms_pallas(g: jax.Array, v: jax.Array, block_d: int = 128,
-                         block_k: int = 128, interpret: bool = False
-                         ) -> jax.Array:
-    """``g (d, d)``, ``v (d, k)`` -> ``||g @ v||_2`` per column, ``(k,)``."""
-    d, d2 = g.shape
-    dv, k = v.shape
+def project_norms_table_pallas(grams: jax.Array, v_table: jax.Array,
+                               block_u: int, block_c: int,
+                               interpret: bool = False) -> jax.Array:
+    """``grams (B, d, d)``, ``v_table (d, C)`` -> ``(B, C)`` fp32 norms.
+
+    ``B`` and ``C`` must be multiples of ``block_u`` and ``block_c``."""
+    b, d, d2 = grams.shape
+    dv, c = v_table.shape
     if d != d2 or dv != d:
-        raise ValueError(f"bad shapes g={g.shape} v={v.shape}")
-    if d % block_d or k % block_k:
-        raise ValueError(f"{(d, k)} not divisible by ({block_d}, {block_k})")
-    n_row = d // block_d
-    n_inner = d // block_d
-    grid = (k // block_k, n_row, n_inner)
-    out = pl.pallas_call(
-        functools.partial(_kernel, n_row=n_row, n_inner=n_inner),
-        grid=grid,
+        raise ValueError(f"bad shapes grams={grams.shape} "
+                         f"v_table={v_table.shape}")
+    if b % block_u or c % block_c:
+        raise ValueError(f"{(b, c)} not divisible by ({block_u}, {block_c})")
+    return pl.pallas_call(
+        _kernel,
+        grid=(b // block_u, c // block_c),
         in_specs=[
-            pl.BlockSpec((block_d, block_d), lambda kk, r, c: (r, c)),
-            pl.BlockSpec((block_d, block_k), lambda kk, r, c: (c, kk)),
+            pl.BlockSpec((block_u, d, d), lambda u, j: (u, 0, 0)),
+            pl.BlockSpec((d, block_c), lambda u, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_k), lambda kk, r, c: (0, kk)),
-        out_shape=jax.ShapeDtypeStruct((1, k), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_d, block_k), jnp.float32),
-                        pltpu.VMEM((1, block_k), jnp.float32)],
+        out_specs=pl.BlockSpec((block_u, block_c), lambda u, j: (u, j)),
+        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         interpret=interpret,
-    )(g, v)
-    return out[0]
+    )(grams, v_table)

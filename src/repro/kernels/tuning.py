@@ -36,7 +36,8 @@ from repro.kernels import dispatch
 __all__ = ["KERNELS", "shape_bucket", "cache_key", "cache_path",
            "heuristic_blocks", "get_blocks", "autotune", "lookup",
            "record", "clear_cache", "divisor_block",
-           "featurize_gram_vmem_bytes", "vmem_budget_bytes",
+           "featurize_gram_vmem_bytes", "eigproject_vmem_bytes",
+           "vmem_budget_bytes",
            "SCOPED_VMEM_BYTES"]
 
 _ENV = "REPRO_TUNE_CACHE"
@@ -191,8 +192,15 @@ def heuristic_blocks(kernel: str, **dims: int) -> dict:
         return {"block_n": _featurize_gram_block_n(**dims),
                 "double_buffer": True}
     if kernel == "eigproject":
-        return {"block_d": tile(dims["d"], 256, 2048),
-                "block_k": tile(dims["k"], 256, 2048)}
+        # b Grams against a k-column signature table; b defaults to the
+        # one-Gram call
+        b, d, k = dims.get("b", 1), dims["d"], dims["k"]
+        if lowered:
+            return _eigproject_blocks(b, d, k, dims.get("itemsize", 4))
+        block_c = min(_round_lane(k), 2048)
+        cap = max(_SUBLANE, (1 << 24) // (_round_lane(d) * block_c))
+        return {"block_u": b if b <= cap else cap // 8 * 8,
+                "block_c": block_c}
     if kernel == "linkage":
         return {"block": divisor_block(dims["n"],
                                        cap=512 if lowered else 4096)}
@@ -220,6 +228,18 @@ def featurize_gram_vmem_bytes(block_n: int, m: int, d: int,
     m, d = _round_lane(m), _round_lane(d)
     return (2 * block_n * m * itemsize + m * d * itemsize
             + 2 * d * d * 4 + block_n * d * (4 + itemsize))
+
+
+def eigproject_vmem_bytes(block_u: int, block_c: int, d: int,
+                          itemsize: int) -> int:
+    """VMEM the table-projection ``eigproject`` kernel allocates: the
+    double-buffered ``(block_u, d, d)`` Gram block and ``(d, block_c)``
+    column tile, the f32 ``(block_u * d, block_c)`` product and its
+    square, and the double-buffered f32 ``(block_u, block_c)`` output
+    tile.  ``d`` is lane-padded as the wrapper pads it."""
+    d = _round_lane(d)
+    return (2 * block_u * d * d * itemsize + 2 * d * block_c * itemsize
+            + 2 * block_u * d * block_c * 4 + 2 * block_u * block_c * 4)
 
 
 def vmem_budget_bytes(kind: str | None = None) -> int:
@@ -253,6 +273,39 @@ def _featurize_gram_block_n(n: int, m: int, d: int, itemsize: int) -> int:
     return block
 
 
+def _eigproject_blocks(b: int, d: int, k: int, itemsize: int) -> dict:
+    """Lowered plan for ``b`` Grams against a ``k``-column table: the
+    widest column tile (a lane-multiple divisor of the padded table, <=
+    512) beside which at least 8 users (or all ``b``) fit, then the most
+    users per block (all ``b`` when they fit, else a power of two from 8
+    to 256, so a power-of-two population needs no padding) whose
+    ``eigproject_vmem_bytes`` fits ``vmem_budget_bytes``.
+    Raises when no plan fits."""
+    budget = vmem_budget_bytes()
+    cols = _round_lane(k)
+    for block_c in range(min(512, cols), _LANE - 1, -_LANE):
+        if cols % block_c:
+            continue
+        fixed = eigproject_vmem_bytes(0, block_c, d, itemsize)
+        per_user = eigproject_vmem_bytes(1, block_c, d, itemsize) - fixed
+        fit = min(256, (budget - fixed) // per_user)
+        if b <= fit:
+            return {"block_u": b, "block_c": block_c}
+        if fit >= 8:
+            return {"block_u": 1 << (fit.bit_length() - 1),
+                    "block_c": block_c}
+    raise ValueError(
+        f"eigproject: no tile plan fits {budget} B of VMEM for {b} Grams "
+        f"of d={d} at itemsize {itemsize}")
+
+
+def _eigproject_grid(blocks: dict, b: int = 1, k: int = 1, **_) -> str:
+    """The ``users x column tiles`` grid a resolved plan runs."""
+    cols = _round_lane(k)
+    bu, bc = min(blocks["block_u"], b), min(blocks["block_c"], cols)
+    return f"{-(-b // bu)}x{-(-cols // bc)}"
+
+
 def get_blocks(kernel: str, **dims: int) -> dict:
     """The resolved tile plan: heuristic defaults overlaid by any tuned
     cache entry for this kernel x backend x shape-bucket."""
@@ -260,6 +313,8 @@ def get_blocks(kernel: str, **dims: int) -> dict:
     hit = lookup(kernel, **dims)
     if hit:
         blocks.update(hit)
+    if kernel == "eigproject":
+        blocks["grid"] = _eigproject_grid(blocks, **dims)
     dispatch.record_dispatch(kernel, blocks)
     return blocks
 

@@ -287,15 +287,12 @@ def kernel_costs(kernel: str, blocks: dict | None = None,
         return {"flops": 2.0 * n * m * d + 2.0 * n * d * d,
                 "bytes": (n * m + m * d) * itemsize + d * d * 4.0}
     if kernel == "eigproject":
-        d, k = dims["d"], dims["k"]
-        bd = b.get("block_d", 128)
-        bk = b.get("block_k", 128)
-        kblocks = max(1, -(-k // bk))
-        rowblocks = max(1, -(-d // bd))
-        # G re-streams per k-block; V re-streams per row-block
-        return {"flops": 2.0 * d * d * k,
-                "bytes": (kblocks * d * d + rowblocks * d * k) * itemsize
-                + k * 4.0}
+        nb, d, k = dims.get("b", 1), dims["d"], dims["k"]
+        ublocks = max(1, -(-nb // b.get("block_u", nb)))
+        # each Gram streams once; the (d, k) table re-streams per user block
+        return {"flops": 2.0 * nb * d * d * k,
+                "bytes": (nb * d * d + ublocks * d * k) * itemsize
+                + nb * k * 4.0}
     if kernel == "linkage":
         n = dims["n"]
         # two source rows + mask in, one row out, plus the fused reduction

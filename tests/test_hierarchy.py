@@ -198,6 +198,18 @@ class TestHierarchical:
         res = self._run(feats, n_groups=6, assignment=assignment)
         assert clu.adjusted_rand_index(np.asarray(res.labels), tids) == 1.0
 
+    def test_pallas_relevance_matches_jnp(self):
+        """The relevance kernel vmapped over edge groups (``pallas``
+        backend) gives the jnp path's labels."""
+        feats, tids = _mixture(64, seed=9)
+        got = [hierarchical_one_shot(
+            feats, TASKS, cfg=SimilarityConfig(top_k=TOP_K, backend=b),
+            hierarchy_cfg=HierarchyConfig(n_groups=4, group_batch=3),
+            cluster_cfg=ClusterConfig(backend="jnp")).labels
+            for b in ("jnp", "pallas")]
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(got[1]))
+        assert clu.adjusted_rand_index(np.asarray(got[1]), tids) == 1.0
+
     def test_group_batching_invariant(self):
         feats, _ = _mixture(64, seed=7)
         full = self._run(feats, n_groups=8)

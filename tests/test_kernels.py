@@ -66,6 +66,55 @@ class TestEigprojectKernel:
         np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
 
 
+class TestEigprojectTableKernel:
+    """The Gram-stack x signature-table kernel (one call for every user
+    pair) == ``project_norms_ref`` applied Gram by Gram, user by user."""
+
+    @staticmethod
+    def _ref(grams, table, k):
+        """``project_norms_ref`` for each (Gram, user block of k columns)."""
+        return np.stack([
+            np.concatenate([np.asarray(project_norms_ref(g, table[:, j:j + k]))
+                            for j in range(0, table.shape[1], k)])
+            for g in grams])
+
+    @pytest.mark.parametrize("case", [
+        "users_not_block_multiple",      # B = 37 against user blocks of 8
+        "columns_not_lane_multiple",     # d = 70, k = 9 -> C = 333
+        "zero_columns",                  # zero eigenvectors -> exact zeros
+        "vmap_groups",                   # vmapped over edge groups
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_pairwise_ref(self, case, dtype):
+        shapes = {"users_not_block_multiple": (37, 128, 8, 37),
+                  "columns_not_lane_multiple": (37, 70, 9, 37),
+                  "zero_columns": (12, 64, 8, 12),
+                  "vmap_groups": (12, 40, 5, 12)}
+        b, d, k, n = shapes[case]
+        groups = 3 if case == "vmap_groups" else 1
+        rng = np.random.default_rng(b + d + k)
+        g = rng.standard_normal((groups, b, d, d)).astype(np.float32)
+        g = jnp.asarray((g + g.transpose(0, 1, 3, 2)) / 2, dtype)
+        table = rng.standard_normal((groups, d, n * k)).astype(np.float32)
+        if case == "zero_columns":
+            table[:, :, 3 * k:5 * k] = 0.0
+            table[:, :, -1] = 0.0
+        table = jnp.asarray(table, dtype)
+        blocks = ({"block_u": 8, "block_c": 128}
+                  if case == "users_not_block_multiple" else {})
+        fn = lambda gg, tt: proj_ops.project_norms_table(  # noqa: E731
+            gg, tt, interpret=True, **blocks)
+        out = np.asarray(jax.vmap(fn)(g, table) if case == "vmap_groups"
+                         else fn(g[0], table[0])[None])
+        assert out.shape == (groups, b, n * k) and out.dtype == np.float32
+        ref = np.stack([self._ref(g[i], table[i], k) for i in range(groups)])
+        tol = 1e-3 if dtype == jnp.float32 else 6e-2
+        np.testing.assert_allclose(out, ref, rtol=tol, atol=tol * 10)
+        if case == "zero_columns":
+            assert (out[:, :, 3 * k:5 * k] == 0.0).all()
+            assert (out[:, :, -1] == 0.0).all()
+
+
 class TestGramProjectKernel:
     """Fused Gram + cross-projection: ||(X^T X / n) v_k|| without the
     (d, d) Gram — the blockwise engine's Eq.-2 hot path."""
@@ -330,11 +379,14 @@ class TestTilingEdgeCases:
         g = rng.standard_normal((96, 96)).astype(np.float32)
         g = jnp.asarray((g + g.T) / 2)
         v = jnp.asarray(rng.standard_normal((96, 5)), jnp.float32)
-        out = proj_ops.project_norms(g, v, block_d=2048, block_k=2048,
-                                     interpret=True)
+        out = proj_ops.project_norms(g, v, block_c=2048, interpret=True)
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(project_norms_ref(g, v)),
                                    rtol=1e-3, atol=1e-4)
+        table = proj_ops.project_norms_table(g[None], v, block_u=2048,
+                                             block_c=2048, interpret=True)
+        np.testing.assert_allclose(np.asarray(table[0]), np.asarray(out),
+                                   rtol=1e-6, atol=1e-6)
 
     def test_gram_project_single_row(self):
         rng = np.random.default_rng(3)
@@ -439,7 +491,7 @@ class TestTuning:
             for k, val in blocks.items():
                 if isinstance(val, bool):
                     continue
-                if k == "chunk":   # time tile, not a lane axis
+                if k in ("chunk", "block_u"):  # time / user tile, no lanes
                     assert val >= 1, (kernel, k, val)
                     continue
                 assert val >= 1 and val % 128 == 0, (kernel, k, val)
@@ -464,6 +516,35 @@ class TestTuning:
         assert (bigger > 512 or bigger > n + (-n % 16)
                 or tuning.featurize_gram_vmem_bytes(
                     bigger, m, d, itemsize) > budget)
+
+    @pytest.mark.parametrize("b,d,k,itemsize", [
+        (1024, 128, 8192, 4), (1024, 128, 8192, 2), (256, 128, 8192, 4),
+        (37, 70, 333, 4), (1, 128, 8, 4), (64, 256, 512, 4)])
+    def test_eigproject_lowered_plan_fits_vmem(self, monkeypatch, b, d, k,
+                                               itemsize):
+        monkeypatch.setattr(dispatch, "supports_lowering", lambda: True)
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v5 lite")
+        blocks = tuning.get_blocks("eigproject", b=b, d=d, k=k,
+                                   itemsize=itemsize)
+        bu, bc = blocks["block_u"], blocks["block_c"]
+        assert bu == b or (bu % 8 == 0 and bu & (bu - 1) == 0), blocks
+        assert bc % 128 == 0 and (-(-k // 128) * 128) % bc == 0, blocks
+        assert tuning.eigproject_vmem_bytes(bu, bc, d, itemsize) <= (
+            tuning.vmem_budget_bytes())
+        assert blocks["grid"] == (f"{-(-b // bu)}x"
+                                  f"{-(-k // 128) * 128 // bc}")
+        # the one-shot cells' shapes: a grid of ~10^3 steps, not N^2
+        if (b, k) == (1024, 8192):
+            gu, gc = map(int, blocks["grid"].split("x"))
+            assert gu * gc <= 2048
+
+    def test_eigproject_lowered_plan_rejects_oversized_gram(self,
+                                                            monkeypatch):
+        monkeypatch.setattr(dispatch, "supports_lowering", lambda: True)
+        monkeypatch.setattr(dispatch, "device_kind", lambda: "TPU v5 lite")
+        with pytest.raises(ValueError, match="no tile plan fits"):
+            tuning.heuristic_blocks("eigproject", b=64, d=1024, k=1024,
+                                    itemsize=4)
 
     def test_featurize_gram_lowered_plan_rejects_oversized_w(self,
                                                              monkeypatch):

@@ -215,6 +215,39 @@ class TestRawEntryParity:
             ragged, fc, signature_cfg=SignatureConfig(chunk_rows=17)))
         np.testing.assert_allclose(r_raw, r_pre, atol=1e-5)
 
+    def test_pallas_relevance_matches_jnp_ragged_population(self):
+        """run_raw's one relevance kernel over the whole Gram stack == the
+        jnp per-pair maths, on 37 users with ragged rows, the plan pinned
+        to user blocks of 8 (37 is no multiple) and two column tiles."""
+        from repro import obs
+        from repro.core import clustering as clu
+        from repro.kernels import tuning
+
+        raw, task_ids = syn.make_task_feature_mixture(
+            n_users=37, n_samples=48, d=96, n_tasks=3, seed=11)
+        rows = np.random.default_rng(11).integers(20, 49, size=37)
+        ragged = [x[:n] for x, n in zip(raw, rows)]
+        sig_cfg = SignatureConfig(chunk_rows=16)
+        tuning.clear_cache()
+        tuning.record("eigproject", {"block_u": 8, "block_c": 128},
+                      b=37, d=32, k=37 * 6, itemsize=4)
+        try:
+            with obs.scope(True):
+                res_p = ProtocolEngine(sim.SimilarityConfig(
+                    top_k=6, backend="pallas")).run_raw(
+                        ragged, self.FC, signature_cfg=sig_cfg)
+                plan = obs.gauge_value("kernel_blocks", kernel="eigproject")
+        finally:
+            tuning.clear_cache()
+        assert plan == "block_c=128,block_u=8,grid=5x2"
+        res_j = ProtocolEngine(sim.SimilarityConfig(top_k=6)).run_raw(
+            ragged, self.FC, signature_cfg=sig_cfg)
+        r_p, r_j = np.asarray(res_p.similarity), np.asarray(res_j.similarity)
+        np.testing.assert_allclose(r_p, r_j, atol=1e-5)
+        labels_p = clu.hac_clusters(r_p, 3)
+        assert (labels_p == clu.hac_clusters(r_j, 3)).all()
+        assert clu.clustering_accuracy(labels_p, task_ids) == 1.0
+
     def test_oneshot_raw_entry_recovers_tasks(self, mixture):
         raw, task_ids = mixture
         from repro.core import clustering as clu

@@ -117,6 +117,22 @@ def test_eigproject_relevance(compiled_text):
     assert "tpu_custom_call" in text
 
 
+def test_eigproject_table_oneshot_shapes(compiled_text):
+    from repro.kernels import tuning
+    from repro.kernels.eigproject import ops
+
+    # the one-shot cells: 1024 users' Grams at d=128 against the
+    # 1024-user x top-8 signature table, one kernel for all pairs
+    text = compiled_text(partial(ops.project_norms_table, interpret=False),
+                         ((1024, 128, 128), F32), ((128, 8192), F32))
+    assert "tpu_custom_call" in text
+    blocks = tuning.get_blocks("eigproject", b=1024, d=128, k=8192,
+                               itemsize=4)
+    assert tuning.eigproject_vmem_bytes(
+        blocks["block_u"], blocks["block_c"], 128, 4) <= (
+        tuning.SCOPED_VMEM_BYTES["v5 lite"])
+
+
 def test_wkv_chunked_rwkv6_1_6b(compiled_text):
     from repro.kernels.recurrent_scan import ops
 
