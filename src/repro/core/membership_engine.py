@@ -27,8 +27,8 @@ Lifecycle on top of assignment:
                    confidence margins; low-margin / low-affinity arrivals
                    land in the ``unassigned`` bucket (label -1).
   * ``admit``    — append signatures to the table, update prototypes by
-                   streaming mean.
-  * ``evict``    — churn: masked removal + prototype down-date.
+                   streaming mean (one device program on jnp/pallas).
+  * ``evict``    — churn: masked removal + prototype down-date (likewise).
   * ``recluster``— drift trigger: when the unassigned fraction or the
                    prototype-shift norm trips the configured threshold,
                    re-run HAC over the CURRENT table via the
@@ -410,6 +410,84 @@ def _proto_update(protos, counts, delta, m, *, sign: float):
                      jnp.zeros_like(upd)), new_counts
 
 
+# The streaming pair (``_wave_outer_sums``, ``_proto_update``) reaches the
+# lifecycle programs as static arguments, so a function patched into the
+# module is a new cache key rather than one a first trace captured.
+_LIFECYCLE_STATIC = ("outer_sums", "proto_update", "aggregator", "trim_frac",
+                     "mom_groups", "directory_dtype")
+
+
+def _lifecycle_protos(v_t, lab_t, valid, protos, scales, counts, v_d, lab_d,
+                      sign, *, outer_sums, proto_update, aggregator,
+                      trim_frac, mom_groups, directory_dtype):
+    """Prototype step shared by admit (+1) and evict (-1): the streaming
+    mean over the wave ``(v_d, lab_d)``, or a recompute over the new
+    table for the resistant aggregators; then re-quantize."""
+    if aggregator == "mean":
+        delta, m = outer_sums(v_d, lab_d, counts)
+        protos, counts = proto_update(
+            quant.dequantize_directory(protos, scales), counts, delta, m,
+            sign=sign)
+    else:
+        protos, counts = _protos_from_table_robust(
+            v_t, lab_t, valid, n_clusters=protos.shape[0],
+            aggregator=aggregator, trim_frac=trim_frac,
+            mom_groups=mom_groups)
+    table, scales = quant.quantize_directory(protos, directory_dtype)
+    return table, scales, counts
+
+
+@partial(jax.jit, static_argnames=_LIFECYCLE_STATIC)
+def _admit_program(lam_t, v_t, lab_t, valid, protos, scales, counts,
+                   lam_w, v_w, lab_w, **static):
+    """One admit wave: take the first ``B`` free slots in ascending order
+    (``np.flatnonzero(~valid)[:B]``), scatter the wave into them and
+    update the prototypes.  ``info`` is ``[slots..., n_free, n_members]``;
+    the caller commits only if ``n_free >= B``."""
+    cap, b = valid.shape[0], lam_w.shape[0]
+    v_w = v_w.astype(jnp.float32)
+    lab_w = lab_w.astype(jnp.int32)
+    sl = jnp.nonzero(~valid, size=b, fill_value=cap)[0].astype(jnp.int32)
+    n_free = jnp.sum(~valid, dtype=jnp.int32)
+    lam_t = lam_t.at[sl].set(lam_w.astype(jnp.float32))
+    v_t = v_t.at[sl].set(v_w)
+    lab_t = lab_t.at[sl].set(lab_w)
+    valid = valid.at[sl].set(True)
+    table, scales, counts = _lifecycle_protos(
+        v_t, lab_t, valid, protos, scales, counts, v_w, lab_w, 1.0,
+        **static)
+    info = jnp.concatenate(
+        [sl, jnp.stack([n_free, jnp.sum(valid, dtype=jnp.int32)])])
+    return (lam_t, v_t, lab_t, valid, table, scales, counts), info
+
+
+@partial(jax.jit, static_argnames=_LIFECYCLE_STATIC)
+def _evict_program(v_t, lab_t, valid, protos, scales, counts, sl, **static):
+    """Masked removal of slots ``sl`` with the prototype down-date.
+    ``info`` is ``[all slots were occupied, n_members]``; the caller
+    commits only if the first is set."""
+    ok = jnp.all(valid[sl])
+    lab_out = lab_t[sl]
+    lab_t = lab_t.at[sl].set(UNASSIGNED)
+    valid = valid.at[sl].set(False)
+    table, scales, counts = _lifecycle_protos(
+        v_t, lab_t, valid, protos, scales, counts, v_t[sl], lab_out, -1.0,
+        **static)
+    info = jnp.stack([ok.astype(jnp.int32), jnp.sum(valid, dtype=jnp.int32)])
+    return (lab_t, valid, table, scales, counts), info
+
+
+def _arg(x):
+    """A lifecycle program argument: device arrays as they are, anything
+    else as a host array for the program's own upload."""
+    return x if isinstance(x, jax.Array) else np.asarray(x)
+
+
+def _full_message(n: int, n_free: int, capacity: int) -> str:
+    return (f"directory full: {n} arrivals but only {n_free} free slots "
+            f"of {capacity} — grow MembershipConfig.capacity")
+
+
 def _to_host(x, what: str, dtype=None) -> np.ndarray:
     """A blocking device->host read of the lifecycle paths: exactly
     ``np.asarray(x, dtype)`` with telemetry off; with it on, a read of a
@@ -682,55 +760,55 @@ class MembershipEngine:
         st = self._require_state()
         free = np.flatnonzero(~_to_host(st.valid, "valid"))
         if len(free) < n:
-            raise ValueError(
-                f"directory full: {n} arrivals but only {len(free)} free "
-                f"slots of {st.capacity} — grow MembershipConfig.capacity")
+            raise ValueError(_full_message(n, len(free), st.capacity))
         return free[:n].astype(np.int32)
+
+    def _lifecycle_static(self) -> dict:
+        # module globals read per call (see ``_LIFECYCLE_STATIC``)
+        return dict(outer_sums=_wave_outer_sums, proto_update=_proto_update,
+                    aggregator=self.cfg.aggregator,
+                    trim_frac=self.cfg.trim_frac,
+                    mom_groups=self.cfg.mom_groups,
+                    directory_dtype=self.cfg.directory_dtype)
 
     def admit(self, lam, v, labels) -> np.ndarray:
         """Append an assigned wave to the table (streaming-mean prototype
         update; unassigned rows join the table but no prototype).
         Resistant aggregators cannot down-/up-date order statistics in
         O(1), so they pay a windowed recompute over the live table
-        instead.  Returns the occupied slot indices (for ``evict``)."""
+        instead.  Returns the occupied slot indices (for ``evict``).
+        On the device backends this is one program and one read."""
         with obs.span("membership.admit") as sp:
-            slots = self._admit(lam, v, labels)
+            slots, n_members = self._admit(lam, v, labels)
             sp.sync(self.state.protos)
         if obs.enabled():
-            st = self.state
             obs.count("membership.admits", len(slots))
-            obs.gauge("directory_bytes", st.directory_bytes)
+            obs.gauge("directory_bytes", self.state.directory_bytes)
             obs.event("admit", n=len(slots), slots=slots,
-                      n_members=int(st.n_members))
+                      n_members=n_members)
         return slots
 
-    def _admit(self, lam, v, labels) -> np.ndarray:
+    def _admit(self, lam, v, labels) -> tuple[np.ndarray, int]:
         st = self._require_state()
+        if self.on_device:
+            new, info = _admit_program(
+                st.lam, st.v, st.labels, st.valid, st.protos,
+                st.proto_scales, st.counts, _arg(lam), _arg(v),
+                _arg(labels), **self._lifecycle_static())
+            info = _to_host(info, "slots")
+            b = info.shape[0] - 2
+            if info[b] < b:
+                raise ValueError(_full_message(b, int(info[b]),
+                                               st.capacity))
+            lam_t, v_t, lab_t, valid, table, scales, counts = new
+            self.state = dataclasses.replace(
+                st, lam=lam_t, v=v_t, labels=lab_t, valid=valid,
+                protos=table, counts=counts, proto_scales=scales)
+            return info[:b], int(info[b + 1])
         lam = _to_host(lam, "lam", np.float32)
         slots = self._free_slots(lam.shape[0])
         labels = _to_host(labels, "labels", np.int32)
         streaming = self.cfg.aggregator == "mean"
-        if self.on_device:
-            v_w = jnp.asarray(v, jnp.float32)
-            lab_w = jnp.asarray(labels)
-            sl = jnp.asarray(slots)
-            lam_t = st.lam.at[sl].set(jnp.asarray(lam))
-            v_t = st.v.at[sl].set(v_w)
-            lab_t = st.labels.at[sl].set(lab_w)
-            valid = st.valid.at[sl].set(True)
-            if streaming:
-                delta, m = _wave_outer_sums(v_w, lab_w, st.counts)
-                protos, counts = _proto_update(self._dequantize(st),
-                                               st.counts, delta, m,
-                                               sign=1.0)
-            else:
-                protos, counts = self._rebuild_protos(v_t, lab_t, valid,
-                                                      st.n_clusters)
-            table, scales = self._quantize(protos)
-            self.state = dataclasses.replace(
-                st, lam=lam_t, v=v_t, labels=lab_t, valid=valid,
-                protos=table, counts=counts, proto_scales=scales)
-            return slots
         v = _to_host(v, "v", np.float32)
         lam_t, v_t = st.lam.copy(), st.v.copy()
         lab_t, valid = st.labels.copy(), st.valid.copy()
@@ -745,54 +823,53 @@ class MembershipEngine:
         self.state = dataclasses.replace(
             st, lam=lam_t, v=v_t, labels=lab_t, valid=valid,
             protos=table, counts=counts, proto_scales=scales)
-        return slots
+        return slots, int(valid.sum())
 
     def evict(self, slots) -> None:
         """Masked removal of table slots (churn): free the rows and
-        down-date the prototypes by the departing members' projectors."""
+        down-date the prototypes by the departing members' projectors.
+        On the device backends this is one program and one read."""
         with obs.span("membership.evict") as sp:
-            self._evict(slots)
+            n_members = self._evict(slots)
             sp.sync(self.state.protos)
         if obs.enabled():
-            st = self.state
             obs.count("membership.evicts", len(np.asarray(slots)))
-            obs.gauge("directory_bytes", st.directory_bytes)
+            obs.gauge("directory_bytes", self.state.directory_bytes)
             obs.event("evict", n=len(np.asarray(slots)),
-                      slots=np.asarray(slots),
-                      n_members=int(st.n_members))
+                      slots=np.asarray(slots), n_members=n_members)
 
-    def _evict(self, slots) -> None:
+    def _evict(self, slots) -> int:
         st = self._require_state()
         slots = _to_host(slots, "slots", np.int32)
         if len(np.unique(slots)) != len(slots):
             # a repeated slot would down-date the prototype twice for one
             # departure, silently corrupting the streaming mean
             raise ValueError(f"duplicate slots in evict: {slots.tolist()}")
+        if self.on_device:
+            if slots.size and not (-st.capacity <= slots.min()
+                                   and slots.max() < st.capacity):
+                # the program's gather would clamp, not raise
+                raise IndexError(f"evict slots out of range for capacity "
+                                 f"{st.capacity}: {slots.tolist()}")
+            new, info = _evict_program(
+                st.v, st.labels, st.valid, st.protos, st.proto_scales,
+                st.counts, slots, **self._lifecycle_static())
+            ok, n_members = _to_host(info, "evict_ok")
+            if not ok:
+                occupied = _to_host(st.valid, "valid")[slots]
+                raise ValueError(f"evicting empty slots "
+                                 f"{slots[~occupied].tolist()}")
+            lab_t, valid, table, scales, counts = new
+            self.state = dataclasses.replace(
+                st, labels=lab_t, valid=valid,
+                protos=table, counts=counts, proto_scales=scales)
+            return int(n_members)
         occupied = _to_host(st.valid, "valid")[slots]
         if not occupied.all():
             raise ValueError(f"evicting empty slots "
                              f"{slots[~occupied].tolist()}")
         labels_out = _to_host(st.labels, "labels")[slots]
         streaming = self.cfg.aggregator == "mean"
-        if self.on_device:
-            sl = jnp.asarray(slots)
-            lab_t = st.labels.at[sl].set(UNASSIGNED)
-            valid = st.valid.at[sl].set(False)
-            if streaming:
-                delta, m = _wave_outer_sums(st.v[sl],
-                                            jnp.asarray(labels_out),
-                                            st.counts)
-                protos, counts = _proto_update(self._dequantize(st),
-                                               st.counts, delta, m,
-                                               sign=-1.0)
-            else:
-                protos, counts = self._rebuild_protos(st.v, lab_t, valid,
-                                                      st.n_clusters)
-            table, scales = self._quantize(protos)
-            self.state = dataclasses.replace(
-                st, labels=lab_t, valid=valid,
-                protos=table, counts=counts, proto_scales=scales)
-            return
         lab_t, valid = st.labels.copy(), st.valid.copy()
         lab_t[slots], valid[slots] = UNASSIGNED, False
         if streaming:
@@ -805,6 +882,7 @@ class MembershipEngine:
         self.state = dataclasses.replace(st, labels=lab_t, valid=valid,
                                          protos=table, counts=counts,
                                          proto_scales=scales)
+        return int(valid.sum())
 
     def _np_proto_shift(self, st: MembershipState, v: np.ndarray,
                         labels: np.ndarray, sign: float):

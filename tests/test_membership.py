@@ -200,10 +200,113 @@ class TestLifecycle:
         with pytest.raises(ValueError, match="empty slots"):
             eng.evict([eng.state.capacity - 1])
 
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_evicting_out_of_range_slot_raises(self, seed_result, backend):
+        eng = make_engine(seed_result, backend)
+        before = eng.state
+        with pytest.raises(IndexError):
+            eng.evict([0, eng.state.capacity])
+        assert eng.state is before
+
     def test_evicting_duplicate_slots_raises(self, seed_result):
         eng = make_engine(seed_result, "jnp")
         with pytest.raises(ValueError, match="duplicate"):
             eng.evict([0, 0])
+
+    @pytest.mark.parametrize("aggregator", ("mean", "trimmed", "medians"))
+    @pytest.mark.parametrize("dtype", ("f32", "bf16", "int8"))
+    @pytest.mark.parametrize("backend", ("jnp", "pallas"))
+    def test_lifecycle_sequence_matches_numpy(self, seed_result, wave,
+                                              backend, dtype, aggregator):
+        """Admit/evict waves of unequal sizes that refill the holes of
+        earlier evictions: the device programs pick the same slots
+        (holes first, ascending) and keep the same table, counts and
+        prototypes as the numpy host path."""
+        lam_w, v_w, _ = wave
+        lam_w, v_w = np.asarray(lam_w), np.asarray(v_w)
+        kw = dict(directory_dtype=dtype, aggregator=aggregator,
+                  trim_frac=0.2, mom_groups=3)
+        ref = make_engine(seed_result, "numpy", **kw)
+        eng = make_engine(seed_result, backend, **kw)
+        labels = np.asarray(ref.assign(lam_w, v_w).labels).copy()
+        labels[1] = -1                       # one row joins no prototype
+        steps = [("admit", slice(0, 5)), ("evict", [3, 10, 25, 7]),
+                 ("admit", slice(2, 8)), ("evict", [0, 29, 15]),
+                 ("admit", slice(1, 8))]
+        admitted = []
+        for op, arg in steps:
+            if op == "admit":
+                got = eng.admit(lam_w[arg], v_w[arg], labels[arg])
+                want = ref.admit(lam_w[arg], v_w[arg], labels[arg])
+                assert isinstance(got, np.ndarray)
+                np.testing.assert_array_equal(got, want)
+                admitted.append(got.tolist())
+            else:
+                eng.evict(arg)
+                ref.evict(arg)
+            for f in ("labels", "valid", "counts"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(eng.state, f)),
+                    getattr(ref.state, f), err_msg=f"{op} {f}")
+            # a sum in another order may round one storage step apart
+            p_ref = ref.state.protos_f32
+            step = {"f32": 0.0, "bf16": 2.0 ** -7,
+                    "int8": 1.0 / 127}[dtype] * np.abs(p_ref).max()
+            np.testing.assert_allclose(np.asarray(eng.state.protos_f32),
+                                       p_ref, atol=1e-5 + step)
+        # each later admit refilled the holes before the tail, in order
+        assert admitted == [[24, 25, 26, 27, 28], [3, 7, 10, 25, 29, 30],
+                            [0, 15, 29, 31, 32, 33, 34]]
+
+    @pytest.mark.parametrize("refusal", ("full", "empty"))
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_refused_call_commits_nothing(self, seed_result, wave, backend,
+                                          refusal):
+        lam_w, v_w, _ = wave
+        eng = make_engine(seed_result, backend, capacity=N_SEED + 2)
+        before = eng.state
+        with pytest.raises(ValueError,
+                           match="directory full|evicting empty slots"):
+            if refusal == "full":
+                eng.admit(lam_w, v_w, np.zeros(np.asarray(lam_w).shape[0]))
+            else:
+                eng.evict([0, N_SEED + 1])
+        assert eng.state is before
+
+    @pytest.mark.parametrize("fault", ("frozen_update", "half_wave"))
+    def test_programs_look_up_module_functions_per_call(
+            self, seed_result, wave, monkeypatch, fault):
+        """A streaming function patched into the module after the
+        programs were traced at a shape changes what the next call at
+        that shape computes, on admit and on evict alike."""
+        from repro.core import membership_engine as me
+
+        lam_w, v_w, _ = wave
+        labels = np.asarray(make_engine(seed_result, "numpy")
+                            .assign(lam_w, v_w).labels)
+
+        def run():
+            a = make_engine(seed_result, "jnp")
+            a.admit(lam_w, v_w, labels)
+            e = make_engine(seed_result, "jnp")
+            e.evict([0, 1, 2, 5, 8])
+            return [np.asarray(x.state.protos) for x in (a, e)]
+
+        clean = run()
+        if fault == "frozen_update":
+            monkeypatch.setattr(
+                me, "_proto_update",
+                lambda protos, counts, delta, m, sign: (protos, counts))
+        else:
+            orig = me._wave_outer_sums
+
+            def half(v_wave, labels, n_clusters_arr):
+                h = v_wave.shape[0] // 2
+                return orig(v_wave[:h], labels[:h], n_clusters_arr)
+
+            monkeypatch.setattr(me, "_wave_outer_sums", half)
+        for got, want in zip(run(), clean):
+            assert np.abs(got - want).max() > 1e-4
 
     @pytest.mark.parametrize("backend", ("numpy", "jnp"))
     def test_assignment_permutation_invariant(self, seed_result, wave,

@@ -449,12 +449,25 @@ class TestInstrumentation:
             "_fused_global_round"]
         assert "module @jit__fused_run" in seen["_fused_run"]
 
-    def test_lifecycle_host_reads_named(self, oneshot_result):
+    def test_lifecycle_host_reads_named(self, oneshot_result, monkeypatch):
         """One admit -> evict -> drift_stats cycle: every blocking device
         read is a ``membership.host_read`` span under its lifecycle
-        span, named by what it read."""
+        span, named by what it read; admit and evict each dispatch one
+        program, under its own name."""
+        from repro.core import membership_engine as me
+
         eng, lam, v = _device_wave(oneshot_result)
         labels = eng.assign(lam, v).labels
+        calls = {}
+        for attr in ("_admit_program", "_evict_program"):
+            orig = getattr(me, attr)
+
+            def spy(*args, _orig=orig, _attr=attr, **kw):
+                calls.setdefault(_attr, []).append(
+                    _orig.lower(*args, **kw).as_text())
+                return _orig(*args, **kw)
+
+            monkeypatch.setattr(me, attr, spy)
         obs.reset()
         with obs.scope(True):
             slots = eng.admit(lam, v, labels)
@@ -468,10 +481,14 @@ class TestInstrumentation:
                 reads.setdefault(ids[r["parent"]], []).append(
                     r["meta"]["what"])
         assert reads == {
-            "membership.admit": ["lam", "valid", "labels"],
-            "membership.evict": ["valid", "labels"],
+            "membership.admit": ["slots"],
+            "membership.evict": ["evict_ok"],
             "membership.drift_stats": ["valid", "labels", "protos",
                                        "protos0"]}
+        assert [len(calls[a]) for a in ("_admit_program",
+                                         "_evict_program")] == [1, 1]
+        assert "module @jit__admit_program" in calls["_admit_program"][0]
+        assert "module @jit__evict_program" in calls["_evict_program"][0]
         for r in recs:
             if r["name"] in ("membership.admit", "membership.evict"):
                 assert 0.0 <= r["wait_us"] <= r["dur_us"]
